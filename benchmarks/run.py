@@ -16,6 +16,7 @@ import json
 import time
 
 from benchmarks.common import BENCH_MODULES
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -30,6 +31,7 @@ def main() -> None:
                     help="dump a Chrome trace of every coordinator the "
                          "selected benchmarks build (obs layer)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     if only:

@@ -3,6 +3,7 @@ GQA transformer AND an attention-free SSM (different cache structures).
 
   PYTHONPATH=src python examples/serve_decode.py
 """
+import os
 import pathlib
 import subprocess
 import sys
@@ -13,5 +14,5 @@ for arch in ("glm4-9b", "mamba2-2.7b"):
     subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", arch,
          "--smoke", "--prompt-len", "8", "--new-tokens", "6", "--batch", "2"],
-        env={"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "PYTHONPATH": str(root / "src")},
         check=True)

@@ -1,9 +1,10 @@
 """End-to-end glue: load base tables into the store, run queries, oracle.
 
 ``oracle`` executes the same logical query single-threaded over the full
-tables using the relational ops directly — no store, no shuffle, no
-partitioning — giving an independent reference for the distributed engine's
-results (tests/test_query_engine.py).
+tables with the numpy reference operators of ``relational.ops`` — no
+store, no shuffle, no partitioning, no device — giving an independent
+reference for the distributed engine's results, whose workers run the
+device path (tests/test_query_engine.py).
 """
 from __future__ import annotations
 
@@ -144,12 +145,12 @@ def oracle(name: str, tables: dict[str, Table]) -> Table:
         if st["kind"] == "scan":
             t = tables[st["table"]].project(st["columns"]) \
                 if st.get("columns") else tables[st["table"]]
-            t = _ops(t, st.get("ops", []), small)
+            t = OPS.apply_ops(t, st.get("ops", []), small)
         elif st["kind"] == "join":
             left = produced[st["left"]]
             right = produced[st["right"]]
             t = OPS.op_join(left, right, st["lkey"], st["rkey"])
-            t = _ops(t, st.get("ops", []), small)
+            t = OPS.apply_ops(t, st.get("ops", []), small)
         elif st["kind"] == "final_agg":
             t = OPS.merge_partials([produced[st["deps"][0]]],
                                    st.get("keys", []),
@@ -161,8 +162,3 @@ def oracle(name: str, tables: dict[str, Table]) -> Table:
             raise ValueError(st["kind"])
         produced[st["name"]] = t
     return produced[plan["stages"][-1]["name"]]
-
-
-def _ops(t, ops, small):
-    from repro.core.worker import _apply_ops
-    return _apply_ops(t, ops, small)

@@ -2,8 +2,9 @@
 
 A worker receives ONLY its task parameters, reads inputs from the object
 store (base table splits or §3.2 partitioned intermediates), executes its
-compiled operator pipeline, writes its output object(s), and exits. No
-worker-to-worker communication exists — the store is the only medium.
+operator pipeline as one jitted device program (``relational.device_ops``),
+writes its output object(s), and exits. No worker-to-worker
+communication exists — the store is the only medium.
 
 Timing is *not* decided here: the worker moves real bytes eagerly and
 records every store request into a :class:`RequestTimeline`
@@ -15,7 +16,8 @@ being composed privately inside the task. Compute time is measured
 per-thread CPU time x ``compute_scale`` (``time.thread_time``, not
 wall-clock, so running many workers concurrently on the coordinator's
 thread pool does not inflate virtual compute when the GIL or the scheduler
-makes a thread wait).
+makes a thread wait). Time the task's program spends on the device is not
+in that term: this thread only enqueues it and waits.
 
 A Worker instance is used by exactly one task on one executor thread; its
 store client and RNG are task-private, so workers need no locking — the
@@ -33,6 +35,7 @@ from repro.core.plan import out_key
 from repro.core.stragglers import StragglerConfig
 from repro.objectstore.client import ReadReq, RequestTimeline, StoreClient
 from repro.objectstore.store import ObjectStore
+from repro.relational import device_ops as DOPS
 from repro.relational import ops as OPS
 from repro.relational.table import (Table, decode_object, deserialize_segment,
                                     deserialize_table, partitions_to_object,
@@ -79,24 +82,20 @@ class TaskResult:
     columns_read: int = 0        # column segments this task decoded
 
 
-def _apply_ops(t: Table, ops: list, base_reader) -> Table:
-    for op in ops:
-        kind = op["op"]
-        if kind == "filter":
-            t = OPS.op_filter(t, op["pred"])
-        elif kind == "project":
-            t = OPS.op_project(t, op["columns"])
-        elif kind == "compute":
-            t = OPS.op_compute(t, op["name"], op["expr"])
-        elif kind == "partial_agg":
-            t = OPS.op_aggregate(t, op["keys"],
-                                 [tuple(a) for a in op["aggs"]])
-        elif kind == "broadcast_join":
-            small = base_reader(op["table"])
-            t = OPS.op_join(t, small, op["lkey"], op["rkey"])
-        else:
-            raise ValueError(kind)
-    return t
+def _builds(ops: list, base_reader) -> dict:
+    """The small tables of a stage's broadcast joins (each read is
+    charged to the task as GETs)."""
+    return {op["table"]: base_reader(op["table"])
+            for op in ops if op["op"] == "broadcast_join"}
+
+
+def _partition(st: dict, n_out_parts: int) -> tuple[str, int] | None:
+    # a partitioned producer always writes the §3.2 format — including
+    # the degenerate 1-consumer fan-out (planner ntasks=1 configs), so
+    # consumers can parse the header unconditionally
+    if st.get("partition") and n_out_parts >= 1:
+        return st["partition"]["key"], n_out_parts
+    return None
 
 
 class Worker:
@@ -194,13 +193,16 @@ class Worker:
             datas, t_in = self._read_whole([(split_key, avail, None)], now)
             c0 = time.thread_time()
             t = decode_object(datas[0], st.get("columns"), key=split_key)
+        part = _partition(st, n_out_parts)
         # a zone-map-pruned split decodes to a column-less table; its ops
         # are provably no-rows-pass, so skip them (filters would KeyError)
         if t.cols:
-            t = _apply_ops(t, st.get("ops", []), base_reader)
+            ops = st.get("ops", [])
+            out = DOPS.run(t, ops, _builds(ops, base_reader), part)
+        else:
+            out = [Table({})] * n_out_parts if part else t
         comp = (time.thread_time() - c0) * self.compute_scale
-        return self._emit(query, st, task_id, t, t_in + comp, comp,
-                          n_out_parts)
+        return self._emit(query, st, task_id, out, t_in + comp, comp)
 
     def run_join(self, query: str, st: dict, task_id: int,
                  left_inputs: list[PartInput], right_inputs: list[PartInput],
@@ -211,14 +213,18 @@ class Worker:
         c0 = time.thread_time()
         left = Table.concat([t for tabs in lt for t in tabs])
         right = Table.concat([t for tabs in rt for t in tabs])
+        part = _partition(st, n_out_parts)
         if len(left) and len(right):
-            t = OPS.op_join(left, right, st["lkey"], st["rkey"])
-            t = _apply_ops(t, st.get("ops", []), base_reader)
+            ops = st.get("ops", [])
+            builds = _builds(ops, base_reader)
+            builds[st["right"]] = right
+            join = {"op": "join", "table": st["right"], "lkey": st["lkey"],
+                    "rkey": st["rkey"]}
+            out = DOPS.run(left, [join] + ops, builds, part)
         else:
-            t = Table({})
+            out = [Table({})] * n_out_parts if part else Table({})
         comp = (time.thread_time() - c0) * self.compute_scale
-        return self._emit(query, st, task_id, t, t2 + comp, comp,
-                          n_out_parts)
+        return self._emit(query, st, task_id, out, t2 + comp, comp)
 
     def run_combine(self, query: str, st: dict, task_id: int,
                     inputs: list[PartInput], now: float) -> TaskResult:
@@ -264,20 +270,17 @@ class Worker:
                           columns_read=self.client.columns_read)
 
     # ------------------------------------------------------------- output
-    def _emit(self, query, st, task_id, t: Table, now, comp,
-              n_out_parts: int) -> TaskResult:
+    def _emit(self, query, st, task_id, out: Table | list[Table], now,
+              comp) -> TaskResult:
+        """Write a task's output: a Table, or its hash partitions as one
+        §3.2 partitioned object."""
         key = out_key(query, st["name"], task_id)
-        # a partitioned producer always writes the §3.2 format — including
-        # the degenerate 1-consumer fan-out (planner ntasks=1 configs), so
-        # consumers can parse the header unconditionally
         ncols = 0
-        if st.get("partition") and n_out_parts >= 1:
-            parts = OPS.op_partition(t, st["partition"]["key"], n_out_parts) \
-                if len(t) else [Table({})] * n_out_parts
-            payload = partitions_to_object(parts)
-            ncols = next((len(p.cols) for p in parts if p.cols), 0)
+        if isinstance(out, list):
+            payload = partitions_to_object(out)
+            ncols = next((len(p.cols) for p in out if p.cols), 0)
         else:
-            payload = serialize_table(t)
+            payload = serialize_table(out)
         self.timeline.record_compute(comp)
         self.client.write(key, payload, now,
                           bill_nbytes=st.get("out_bytes_floor"))

@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each with a jnp reference (ref.py) and a jitted
+wrapper (ops.py). A wrapper compiles its kernel for the TPU and runs it
+in the Pallas interpreter on any other backend."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """True off the TPU, where a Pallas kernel can only be interpreted."""
+    return jax.default_backend() != "tpu"

@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            blk_q: int = DEFAULT_BLOCK,
                            blk_k: int = DEFAULT_BLOCK,
-                           interpret: bool = True):
+                           interpret: bool):
     """q [B,Sq,H,D], k/v [B,Skv,H,D] (kv already head-expanded).
 
     Host side (ops.py) pads D to 128 multiples and S to block multiples.

@@ -1,5 +1,6 @@
 """jit'd wrapper for flash_gqa: pads D to lane multiples / S to blocks,
-expands GQA kv heads, dispatches Pallas vs jnp-oracle."""
+expands GQA kv heads, dispatches Pallas vs jnp-oracle. The kernel is
+interpreted off the TPU (``kernels.interpret_mode``)."""
 from __future__ import annotations
 
 import functools
@@ -7,16 +8,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_gqa.flash_gqa import flash_attention_pallas
 from repro.kernels.flash_gqa.ref import attention_ref
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",
-                                             "use_pallas", "interpret",
-                                             "blk"))
+                                             "use_pallas", "blk"))
 def flash_gqa(q, k, v, *, causal: bool = True, window: int = 0,
-              use_pallas: bool = True, interpret: bool = True,
-              blk: int = 128):
+              use_pallas: bool = True, blk: int = 128):
     """q [B,Sq,H,D]; k/v [B,Skv,Hkv,D] with H % Hkv == 0."""
     B, Sq, H, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -44,5 +44,6 @@ def flash_gqa(q, k, v, *, causal: bool = True, window: int = 0,
     if padD:
         q = q * jnp.sqrt((D + padD) / D).astype(q.dtype)
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 blk_q=blk, blk_k=blk, interpret=interpret)
+                                 blk_q=blk, blk_k=blk,
+                                 interpret=interpret_mode())
     return out[:, :Sq, :, :D]

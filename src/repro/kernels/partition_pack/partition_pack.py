@@ -81,7 +81,7 @@ def gather_pack_kernel(row_of_ref, rows_ref, buf_ref, *, capacity: int):
 
 
 def pack_pallas(rows: jax.Array, part_ids: jax.Array, n_parts: int,
-                capacity: int, *, interpret: bool = True):
+                capacity: int, *, interpret: bool):
     """Returns (buf [n_parts, capacity, d], counts, slots). Host pads T to a
     multiple of TILE_T (padded ids -> partition n_parts, dropped)."""
     T, d = rows.shape

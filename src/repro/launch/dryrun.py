@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines, before any jax import: jax locks the device
-# count at first init. 512 placeholder host devices back both production
-# meshes: 16x16 single pod and 2x16x16 multi-pod.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this
@@ -22,6 +16,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -235,6 +230,10 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
 
 
 def main():
+    # 512 placeholder host devices back both production meshes (16x16
+    # single pod, 2x16x16 multi-pod). XLA reads the flag when the backend
+    # starts, at the first device query, which comes after this line.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -9,6 +9,7 @@ import argparse
 
 from repro.core.engine import make_engine, run_query
 from repro.core.stragglers import StragglerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.relational.table import DictColumn
 from repro.relational.tpch import QUERIES
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--no-mitigations", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     policy = StragglerConfig.all_off() if args.no_mitigations else None
     coord, tables = make_engine(sf=args.sf, policy=policy)
     kw = {}

@@ -10,6 +10,7 @@ import argparse
 
 from repro.configs.base import get_config
 from repro.configs.smoke import smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.objectstore.store import ObjectStore, StoreConfig
 from repro.runtime.train_loop import ElasticTrainer, JobConfig
@@ -28,6 +29,7 @@ def main():
                     help="inject one worker failure at this global step")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     bundle = build_model(cfg)
     store = ObjectStore(StoreConfig(seed=0, simulate_visibility_lag=False))

@@ -1,9 +1,11 @@
-"""Relational operators (paper §4.1): data-centric, vectorized.
+"""Relational operators (paper §4.1): data-centric, vectorized numpy.
 
-Compute-heavy inner loops (hashing, join matching, grouped aggregation) run
-in jnp — the JAX analogue of the paper's compiled type-specialized pipelines
-(jax.jit fuses the op pipeline the way Starling's C++ codegen fuses nested
-loops). Dynamic-shape glue (filters, unique) is numpy.
+This module is the reference semantics of the operators and nothing else
+on the worker path: ``engine.oracle`` runs a query through ``apply_ops``
+and ``op_join`` here, independently of the workers, whose per-row work
+runs as one jitted device program per task (``relational.device_ops``).
+The coordinator-side final stage still uses ``merge_partials`` and
+``op_sort_limit`` over the few-row partial aggregates.
 
 Expression mini-language (JSON-able), used by predicates and projections:
   column:      "l_quantity"
@@ -72,7 +74,6 @@ def op_compute(t: Table, name: str, expr) -> Table:
 # ---------------------------------------------------------------------------
 
 def _splitmix64(x: np.ndarray) -> np.ndarray:
-    # numpy: jnp lacks true uint64 without x64 mode
     with np.errstate(over="ignore"):
         x = x.astype(np.uint64)
         x = x + np.uint64(0x9E3779B97F4A7C15)
@@ -161,13 +162,14 @@ def op_aggregate(t: Table, keys: list[str], aggs: list[tuple]) -> Table:
             out[k] = DictColumn(uniq[:, i].astype(np.uint32), c.values)
         else:
             out[k] = uniq[:, i].astype(np.asarray(c).dtype)
-    # segment reductions in f64 numpy (jnp is f32 without x64 — TPC-H sums
-    # need double); bincount/ufunc.at are vectorized C loops.
+    # segment reductions in f64 (TPC-H sums need double)
     for name, fn, expr in aggs:
         v = eval_expr(t, expr) if expr is not None else np.ones(len(t))
         v = np.asarray(v, np.float64)
         if fn in ("sum", "avg"):
-            out[name] = np.bincount(inv, weights=v, minlength=ng)
+            # float64 even for no rows (bincount returns int64 there)
+            out[name] = np.bincount(inv, weights=v, minlength=ng
+                                    ).astype(np.float64)
             if fn == "avg":
                 out[name + "__count"] = np.bincount(
                     inv, minlength=ng).astype(np.float64)
@@ -184,6 +186,26 @@ def op_aggregate(t: Table, keys: list[str], aggs: list[tuple]) -> Table:
         else:
             raise ValueError(fn)
     return Table(out)
+
+
+def apply_ops(t: Table, ops: list, base_reader) -> Table:
+    """Run a stage's plan ops in order (``base_reader(name)`` gives a
+    broadcast join's small table)."""
+    for op in ops:
+        kind = op["op"]
+        if kind == "filter":
+            t = op_filter(t, op["pred"])
+        elif kind == "project":
+            t = op_project(t, op["columns"])
+        elif kind == "compute":
+            t = op_compute(t, op["name"], op["expr"])
+        elif kind == "partial_agg":
+            t = op_aggregate(t, op["keys"], [tuple(a) for a in op["aggs"]])
+        elif kind == "broadcast_join":
+            t = op_join(t, base_reader(op["table"]), op["lkey"], op["rkey"])
+        else:
+            raise ValueError(kind)
+    return t
 
 
 def merge_partials(parts: list[Table], keys: list[str],

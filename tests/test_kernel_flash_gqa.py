@@ -26,8 +26,7 @@ def test_flash_matches_oracle(B, Sq, Skv, H, Hkv, D, causal, window, dtype):
     q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (B, Skv, Hkv, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, Skv, Hkv, D), jnp.float32).astype(dtype)
-    got = flash_gqa(q, k, v, causal=causal, window=window, use_pallas=True,
-                    interpret=True)
+    got = flash_gqa(q, k, v, causal=causal, window=window, use_pallas=True)
     rep = H // Hkv
     want = attention_ref(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
                          causal=causal, window=window)
@@ -44,7 +43,7 @@ def test_matches_model_chunked_attention():
     q = jax.random.normal(ks[0], (2, 256, 4, 64))
     k = jax.random.normal(ks[1], (2, 256, 4, 64))
     v = jax.random.normal(ks[2], (2, 256, 4, 64))
-    a = flash_gqa(q, k, v, causal=True, use_pallas=True, interpret=True)
+    a = flash_gqa(q, k, v, causal=True, use_pallas=True)
     b = chunked_attention(q, k, v, causal=True, chunk=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)

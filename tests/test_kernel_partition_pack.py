@@ -20,7 +20,7 @@ def test_pallas_matches_oracle(T, P, C, d, dtype):
     rows = jax.random.normal(k1, (T, d), jnp.float32).astype(dtype)
     ids = jax.random.randint(k2, (T,), 0, P, jnp.int32)
     buf_p, cnt_p, slot_p = partition_pack(rows, ids, n_parts=P, capacity=C,
-                                          use_pallas=True, interpret=True)
+                                          use_pallas=True)
     buf_r, cnt_r, slot_r = partition_pack(rows, ids, n_parts=P, capacity=C,
                                           use_pallas=False)
     np.testing.assert_array_equal(np.asarray(cnt_p), np.asarray(cnt_r))

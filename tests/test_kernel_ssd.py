@@ -31,8 +31,7 @@ def _inputs(B, S, H, P, G, N, seed=0):
 @pytest.mark.parametrize("B,S,H,P,G,N,chunk", CASES)
 def test_pallas_matches_chunked_oracle(B, S, H, P, G, N, chunk):
     x, dt, A, Bm, Cm = _inputs(B, S, H, P, G, N, seed=S + P)
-    y_k, st_k = ssd(x, dt, A, Bm, Cm, chunk=chunk, use_pallas=True,
-                    interpret=True)
+    y_k, st_k = ssd(x, dt, A, Bm, Cm, chunk=chunk, use_pallas=True)
     y_r, st_r = ssd_chunked(x, dt, A, Bm, Cm, chunk)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                rtol=2e-4, atol=2e-4)
