@@ -119,6 +119,7 @@ from repro.faults.retry import RetryPolicy
 from repro.objectstore.client import RequestTimeline
 from repro.objectstore.latency import poll_until_visible, visible_twin
 from repro.objectstore.store import ObjectStore
+from repro.obs import spans
 from repro.relational.table import Table, decode_object, object_meta
 
 INVOKE_OVERHEAD_S = 0.030            # Lambda invoke + runtime startup
@@ -420,8 +421,12 @@ class Coordinator:
             with self._cache_lock:
                 cached = self._small_cache.get(table)
             if cached is None:
-                tabs = [decode_object(self.store.get(k), key=k)
-                        for k in self.base_splits[table]]
+                tabs = []
+                for k in self.base_splits[table]:
+                    with spans.span(spans.STORE_GET):
+                        data = self.store.get(k)
+                    with spans.span(spans.FORMAT_DECODE):
+                        tabs.append(decode_object(data, key=k))
                 cached = Table.concat(tabs)
                 with self._cache_lock:
                     self._small_cache[table] = cached
@@ -601,106 +606,114 @@ class Coordinator:
                              "tenant entries")
         tstates: dict[str, _TenantState] = {}
         runs: list[_Run] = []
-        for ridx, (plan, arr) in enumerate(zip(plans, arrivals)):
-            if afters[ridx] is not None:
-                arr = math.nan          # set when the upstream run finishes
-            validate_plan(plan)
-            seen = self._name_counts.get(plan["name"], 0)
-            self._name_counts[plan["name"]] = seen + 1
-            uname = plan["name"] if seen == 0 else f"{plan['name']}@{seen}"
-            expanded = self._expand_plan(plan, uname)
-            validate_plan(expanded)
-            run = _Run(ridx, expanded, plan["name"], arr)
-            spec = tenant_list[ridx]
-            if spec is not None:
-                if spec.name not in tstates:
-                    tstates[spec.name] = _TenantState(spec)
-                run.tenant = tstates[spec.name]
-            for stage in run.stages:
-                stage.n = self._ntasks(expanded, stage.st)
-                stage.undispatched = stage.n
-                stage.tasks = [_Task() for _ in range(stage.n)]
-                run.keys[stage.st["name"]] = [None] * stage.n
-                run.ends[stage.st["name"]] = [0.0] * stage.n
-                run.outcols[stage.st["name"]] = [0] * stage.n
-            runs.append(run)
+        with spans.span(spans.QUERY) as query_span:
+            for ridx, (plan, arr) in enumerate(zip(plans, arrivals)):
+                if afters[ridx] is not None:
+                    arr = math.nan    # set when the upstream run finishes
+                with spans.span(spans.PLAN) as plan_span:
+                    validate_plan(plan)
+                    seen = self._name_counts.get(plan["name"], 0)
+                    self._name_counts[plan["name"]] = seen + 1
+                    uname = plan["name"] if seen == 0 \
+                        else f"{plan['name']}@{seen}"
+                    plan_span.set_metadata(query=uname)
+                    expanded = self._expand_plan(plan, uname)
+                    validate_plan(expanded)
+                run = _Run(ridx, expanded, plan["name"], arr)
+                spec = tenant_list[ridx]
+                if spec is not None:
+                    if spec.name not in tstates:
+                        tstates[spec.name] = _TenantState(spec)
+                    run.tenant = tstates[spec.name]
+                for stage in run.stages:
+                    stage.n = self._ntasks(expanded, stage.st)
+                    stage.undispatched = stage.n
+                    stage.tasks = [_Task() for _ in range(stage.n)]
+                    run.keys[stage.st["name"]] = [None] * stage.n
+                    run.ends[stage.st["name"]] = [0.0] * stage.n
+                    run.outcols[stage.st["name"]] = [0] * stage.n
+                runs.append(run)
+            query_span.set_metadata(query=" ".join(r.name for r in runs))
 
-        n_slots = self.max_parallel if max_parallel is None \
-            else max(int(max_parallel), 1)
-        open_loop = [a for a, dep in zip(arrivals, afters) if dep is None]
-        # slot = (free_t, sid); the sid gives each slot a warm-pool identity
-        # without changing which free time is popped (bit-identical multiset)
-        slots = [(min(open_loop), i) for i in range(n_slots)]
-        heapq.heapify(slots)
-        virgin = set(range(n_slots)) if self.coldstart else set()
-        events = EventQueue()           # (t, kind, ridx, sidx, tidx, rq)
-        pending: deque[tuple[int, int, int]] = deque()   # tasks w/o a slot
-        outstanding: dict = {}                # future -> (run, stage, tidx)
+            n_slots = self.max_parallel if max_parallel is None \
+                else max(int(max_parallel), 1)
+            open_loop = [a for a, dep in zip(arrivals, afters) if dep is None]
+            # slot = (free_t, sid); the sid gives each slot a warm-pool
+            # identity without changing which free time is popped
+            # (bit-identical multiset)
+            slots = [(min(open_loop), i) for i in range(n_slots)]
+            heapq.heapify(slots)
+            virgin = set(range(n_slots)) if self.coldstart else set()
+            events = EventQueue()           # (t, kind, ridx, sidx, tidx, rq)
+            pending: deque[tuple[int, int, int]] = deque()   # tasks w/o a slot
+            outstanding: dict = {}          # future -> (run, stage, tidx)
 
-        with ThreadPoolExecutor(max_workers=self.executor_workers) as pool:
-            ctx = _Ctx(runs, events, slots, pending, outstanding, pool,
-                       deps_map, virgin, tenancy=bool(tstates))
-            self.tenant_states = tstates
-            for run in runs:
-                if not math.isnan(run.t0):
-                    self._arrive(ctx, run, run.t0)
-            while events or outstanding:
-                while outstanding and not self._can_pop(events, outstanding):
-                    self._await_some(ctx)
-                if not events:
-                    continue
-                t, kind, ridx, sidx, tidx, rq = events.pop()
-                run, stage = runs[ridx], runs[ridx].stages[sidx]
-                if kind == _READY:
-                    if run.failed:
-                        continue        # §3: an exhausted budget failed it
-                    if not stage.dispatched and \
-                            not self._deps_resolved(run, stage):
-                        # a late-dispatched producer hasn't executed yet;
-                        # wall-clock wait only, virtual state is unchanged.
-                        # Defer past the heap top when nothing is in flight
-                        # (a fault-path retry may be what re-runs the dep)
-                        if outstanding:
-                            events.push(t, kind, ridx, sidx, tidx, rq)
-                            self._await_some(ctx)
-                        else:
-                            events.push(events.peek_t() + _EPS,
-                                        kind, ridx, sidx, tidx, rq)
+            with ThreadPoolExecutor(max_workers=self.executor_workers) as pool:
+                ctx = _Ctx(runs, events, slots, pending, outstanding, pool,
+                           deps_map, virgin, tenancy=bool(tstates))
+                self.tenant_states = tstates
+                for run in runs:
+                    if not math.isnan(run.t0):
+                        self._arrive(ctx, run, run.t0)
+                while events or outstanding:
+                    while outstanding and \
+                            not self._can_pop(events, outstanding):
+                        self._await_some(ctx)
+                    if not events:
                         continue
-                    # journal AFTER the re-push guard: re-pops depend on
-                    # wall clock, consumed events are width-invariant
+                    t, kind, ridx, sidx, tidx, rq = events.pop()
+                    run, stage = runs[ridx], runs[ridx].stages[sidx]
+                    if kind == _READY:
+                        if run.failed:
+                            continue    # §3: an exhausted budget failed it
+                        if not stage.dispatched and \
+                                not self._deps_resolved(run, stage):
+                            # a late-dispatched producer hasn't executed yet;
+                            # wall-clock wait only, virtual state is unchanged.
+                            # Defer past the heap top when nothing is in flight
+                            # (a fault-path retry may be what re-runs the dep)
+                            if outstanding:
+                                events.push(t, kind, ridx, sidx, tidx, rq)
+                                self._await_some(ctx)
+                            else:
+                                events.push(events.peek_t() + _EPS,
+                                            kind, ridx, sidx, tidx, rq)
+                            continue
+                        # journal AFTER the re-push guard: re-pops depend on
+                        # wall clock, consumed events are width-invariant
+                        if self.journal is not None:
+                            self.journal.observe(
+                                (t, kind, ridx, sidx, tidx, rq))
+                        self._on_ready(ctx, run, stage, t)
+                        continue
                     if self.journal is not None:
                         self.journal.observe((t, kind, ridx, sidx, tidx, rq))
-                    self._on_ready(ctx, run, stage, t)
-                    continue
-                if self.journal is not None:
-                    self.journal.observe((t, kind, ridx, sidx, tidx, rq))
-                if kind == _DONE:
-                    self._on_done(ctx, run, stage, tidx, t)
-                elif kind == _BACKUP:
-                    self._on_backup(ctx, run, stage, tidx, t)
-                elif kind in (_GET_ISSUE, _VISIBLE):
-                    self._on_get_issue(ctx, run, stage, tidx, rq, t,
-                                       retargeted=(kind == _VISIBLE))
-                elif kind == _PUT_ISSUE:
-                    self._on_put_issue(ctx, run, stage, tidx, rq, t)
-                elif kind == _DUP:
-                    self._on_dup(ctx, run, stage, tidx, rq, t)
-                elif kind == _INVOKE_FAIL:
-                    self._on_invoke_fail(ctx, run, stage, tidx, rq, t)
-                elif kind == _RETRY:
-                    self._on_retry(ctx, run, stage, tidx, rq, t)
-                elif kind == _ADMIT:
-                    self._on_admit(ctx, run, t)
-                elif kind == _RELEASE:
-                    self._on_release(ctx, run, t)
-                else:                   # _GET_DONE / _PUT_DONE
-                    self._on_req_done(ctx, run, stage, tidx, rq, t,
-                                      is_put=(kind == _PUT_DONE))
+                    if kind == _DONE:
+                        self._on_done(ctx, run, stage, tidx, t)
+                    elif kind == _BACKUP:
+                        self._on_backup(ctx, run, stage, tidx, t)
+                    elif kind in (_GET_ISSUE, _VISIBLE):
+                        self._on_get_issue(ctx, run, stage, tidx, rq, t,
+                                           retargeted=(kind == _VISIBLE))
+                    elif kind == _PUT_ISSUE:
+                        self._on_put_issue(ctx, run, stage, tidx, rq, t)
+                    elif kind == _DUP:
+                        self._on_dup(ctx, run, stage, tidx, rq, t)
+                    elif kind == _INVOKE_FAIL:
+                        self._on_invoke_fail(ctx, run, stage, tidx, rq, t)
+                    elif kind == _RETRY:
+                        self._on_retry(ctx, run, stage, tidx, rq, t)
+                    elif kind == _ADMIT:
+                        self._on_admit(ctx, run, t)
+                    elif kind == _RELEASE:
+                        self._on_release(ctx, run, t)
+                    else:                   # _GET_DONE / _PUT_DONE
+                        self._on_req_done(ctx, run, stage, tidx, rq, t,
+                                          is_put=(kind == _PUT_DONE))
 
-        self.last_event_pops = events.popped
-        self.last_event_depth_hwm = events.depth_hwm
-        return [self._finish(run) for run in runs]
+            self.last_event_pops = events.popped
+            self.last_event_depth_hwm = events.depth_hwm
+            return [self._finish(run) for run in runs]
 
     # ----------------------------------------------------- loop plumbing
     @staticmethod
@@ -724,7 +737,9 @@ class Coordinator:
         """Block until >=1 real execution finishes; adopt its timeline.
         Only deterministic state is touched, in deterministic per-task ways,
         so wall-clock completion order never leaks into virtual time."""
-        done, _ = wait(list(ctx.outstanding), return_when=FIRST_COMPLETED)
+        with spans.span(spans.SCHED_WAIT):
+            done, _ = wait(list(ctx.outstanding),
+                           return_when=FIRST_COMPLETED)
         for f in done:
             run, stage, tidx = ctx.outstanding.pop(f)
             self._resolve(ctx, run, stage, tidx, f.result())
@@ -1772,7 +1787,8 @@ class Coordinator:
     # ---------------------------------------------------------- task build
     def _build_task(self, run: _Run, st, ti, w: Worker, start):
         """Bind a task's inputs NOW (event thread, deterministic state) and
-        return a zero-arg callable for the executor."""
+        return a zero-arg callable for the executor, which runs the task
+        inside its ``repro.task`` span."""
         query = run.name
         kind = st["kind"]
         base_reader = self._base_reader(w)
@@ -1782,16 +1798,16 @@ class Coordinator:
             run.nparts[st["name"]] = n_out
             split = self.base_splits[st["table"]][
                 ti % len(self.base_splits[st["table"]])]
-            return lambda: w.run_scan(query, st, ti, split, 0.0, start,
+            call = lambda: w.run_scan(query, st, ti, split, 0.0, start,
                                       n_out, base_reader)
-        if kind == "join":
+        elif kind == "join":
             n_out = self._consumer_tasks(plan, st)
             run.nparts[st["name"]] = n_out
             left = self._side_inputs(run, st, "left", ti)
             right = self._side_inputs(run, st, "right", ti)
-            return lambda: w.run_join(query, st, ti, left, right, start,
+            call = lambda: w.run_join(query, st, ti, left, right, start,
                                       n_out, base_reader)
-        if kind == "combine":
+        elif kind == "combine":
             spec = st["assign"][ti]
             src = st["source"]
             inputs = [PartInput(run.keys[src][fi], 0.0,
@@ -1799,13 +1815,22 @@ class Coordinator:
                                 spec["partitions"][1] - 1, src=(src, fi),
                                 n_cols=run.outcols[src][fi])
                       for fi in range(*spec["files"])]
-            return lambda: w.run_combine(query, st, ti, inputs, start)
-        if kind == "final_agg":
+            call = lambda: w.run_combine(query, st, ti, inputs, start)
+        elif kind == "final_agg":
             dep = st["deps"][0]
             inputs = [(k, 0.0, (dep, fi))
                       for fi, k in enumerate(run.keys[dep])]
-            return lambda: w.run_final(query, st, inputs, start)
-        raise ValueError(kind)
+            call = lambda: w.run_final(query, st, inputs, start)
+        else:
+            raise ValueError(kind)
+        # the ids obs.trace.Tracer gives the same task on the virtual clock
+        ids = {"query": query, "stage": st["name"],
+               "task": f"{st['name']}[{ti}]"}
+
+        def traced():
+            with spans.span(spans.TASK, **ids):
+                return call()
+        return traced
 
     def _side_inputs(self, run: _Run, st, side: str, ti) -> list[PartInput]:
         """Which objects + partition ranges feed join task ti from the

@@ -35,6 +35,7 @@ from repro.core.plan import out_key
 from repro.core.stragglers import StragglerConfig
 from repro.objectstore.client import ReadReq, RequestTimeline, StoreClient
 from repro.objectstore.store import ObjectStore
+from repro.obs import spans
 from repro.relational import device_ops as DOPS
 from repro.relational import ops as OPS
 from repro.relational.table import (Table, decode_object, deserialize_segment,
@@ -157,22 +158,23 @@ class Worker:
                                      alt_key=self._alt(pi.key), src=pi.src))
         bodies, t2 = self.client.read_many(body_reqs, t1)
         out: list[list[Table]] = []
-        for pi, (hdr, sel), body, req in zip(inputs, metas, bodies,
-                                             body_reqs):
-            base = req.start
-            tabs = []
-            for j in range(pi.first, pi.last + 1):
-                cis = sel if sel is not None else range(hdr.n_columns)
-                cols = {}
-                for ci in cis:
-                    slo, shi = hdr.seg_bounds(j, ci)
-                    cols[hdr.columns[ci]] = deserialize_segment(
-                        body[hdr.data_start + slo - base:
-                             hdr.data_start + shi - base])
-                self.client.columns_read += len(cols)
-                t = Table(cols)
-                tabs.append(t if len(t) else Table({}))
-            out.append(tabs)
+        with spans.span(spans.FORMAT_DECODE):
+            for pi, (hdr, sel), body, req in zip(inputs, metas, bodies,
+                                                 body_reqs):
+                base = req.start
+                tabs = []
+                for j in range(pi.first, pi.last + 1):
+                    cis = sel if sel is not None else range(hdr.n_columns)
+                    cols = {}
+                    for ci in cis:
+                        slo, shi = hdr.seg_bounds(j, ci)
+                        cols[hdr.columns[ci]] = deserialize_segment(
+                            body[hdr.data_start + slo - base:
+                                 hdr.data_start + shi - base])
+                    self.client.columns_read += len(cols)
+                    t = Table(cols)
+                    tabs.append(t if len(t) else Table({}))
+                out.append(tabs)
         return out, t2
 
     # ------------------------------------------------------------ execution
@@ -192,7 +194,8 @@ class Worker:
         else:
             datas, t_in = self._read_whole([(split_key, avail, None)], now)
             c0 = time.thread_time()
-            t = decode_object(datas[0], st.get("columns"), key=split_key)
+            with spans.span(spans.FORMAT_DECODE):
+                t = decode_object(datas[0], st.get("columns"), key=split_key)
         part = _partition(st, n_out_parts)
         # a zone-map-pruned split decodes to a column-less table; its ops
         # are provably no-rows-pass, so skip them (filters would KeyError)
@@ -236,7 +239,8 @@ class Worker:
         parts = [Table.concat([tabs[off] for tabs in per_file])
                  for off in range(last - first + 1)]
         comp = (time.thread_time() - c0) * self.compute_scale
-        payload = partitions_to_object(parts)
+        with spans.span(spans.FORMAT_ENCODE):
+            payload = partitions_to_object(parts)
         key = out_key(query, st["name"], task_id)
         self.timeline.record_compute(comp)
         self.client.write(key, payload, t_in + comp,
@@ -252,16 +256,19 @@ class Worker:
                   now: float) -> TaskResult:
         datas, t_in = self._read_whole(inputs, now)
         c0 = time.thread_time()
-        parts = [deserialize_table(d) for d in datas if len(d) > 8]
-        t = OPS.merge_partials([p for p in parts if len(p)],
-                               st.get("keys", []),
-                               [tuple(a) for a in st.get("aggs", [])])
-        if st.get("sort") and len(t):
-            t = OPS.op_sort_limit(t, [tuple(s) for s in st["sort"]],
-                                  st.get("limit"))
+        with spans.span(spans.FORMAT_DECODE):
+            parts = [deserialize_table(d) for d in datas if len(d) > 8]
+        with spans.span(spans.MERGE):
+            t = OPS.merge_partials([p for p in parts if len(p)],
+                                   st.get("keys", []),
+                                   [tuple(a) for a in st.get("aggs", [])])
+            if st.get("sort") and len(t):
+                t = OPS.op_sort_limit(t, [tuple(s) for s in st["sort"]],
+                                      st.get("limit"))
         comp = (time.thread_time() - c0) * self.compute_scale
         key = out_key(query, st["name"], 0)
-        payload = serialize_table(t)
+        with spans.span(spans.FORMAT_ENCODE):
+            payload = serialize_table(t)
         self.timeline.record_compute(comp)
         self.client.write(key, payload, t_in + comp,
                           bill_nbytes=st.get("out_bytes_floor"))
@@ -276,11 +283,12 @@ class Worker:
         §3.2 partitioned object."""
         key = out_key(query, st["name"], task_id)
         ncols = 0
-        if isinstance(out, list):
-            payload = partitions_to_object(out)
-            ncols = next((len(p.cols) for p in out if p.cols), 0)
-        else:
-            payload = serialize_table(out)
+        with spans.span(spans.FORMAT_ENCODE):
+            if isinstance(out, list):
+                payload = partitions_to_object(out)
+                ncols = next((len(p.cols) for p in out if p.cols), 0)
+            else:
+                payload = serialize_table(out)
         self.timeline.record_compute(comp)
         self.client.write(key, payload, now,
                           bill_nbytes=st.get("out_bytes_floor"))

@@ -26,6 +26,7 @@ import numpy as np
 from repro.core.stragglers import StragglerConfig
 from repro.objectstore.latency import poll_until_visible, visible_twin
 from repro.objectstore.store import ObjectStore
+from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -134,27 +135,28 @@ class StoreClient:
         Recording mode: the real bytes move now; the batch is appended to
         the timeline and the returned end time is the placeholder ``now``
         (the scheduler owns timing)."""
-        conc = min(len(reqs), max(self.policy.parallel_reads, 1)) or 1
-        if self.timeline is not None:
-            datas, specs = [], []
-            for req in reqs:
-                data = self.store.get(req.key, req.start, req.end)
-                datas.append(data)
-                self.gets += 1
-                specs.append(GetSpec(req.key, req.alt_key, len(data),
-                                     req.available_at, req.src))
-            self.timeline.record_gets(specs, conc)
-            return datas, now
-        lanes = [now] * max(self.policy.parallel_reads, 1)
-        out: list[bytes] = []
-        end = now
-        for i, req in enumerate(reqs):
-            lane = i % len(lanes)
-            data, done = self._one_get(req, lanes[lane], conc)
-            lanes[lane] = done
-            end = max(end, done)
-            out.append(data)
-        return out, end
+        with spans.span(spans.STORE_GET):
+            conc = min(len(reqs), max(self.policy.parallel_reads, 1)) or 1
+            if self.timeline is not None:
+                datas, specs = [], []
+                for req in reqs:
+                    data = self.store.get(req.key, req.start, req.end)
+                    datas.append(data)
+                    self.gets += 1
+                    specs.append(GetSpec(req.key, req.alt_key, len(data),
+                                         req.available_at, req.src))
+                self.timeline.record_gets(specs, conc)
+                return datas, now
+            lanes = [now] * max(self.policy.parallel_reads, 1)
+            out: list[bytes] = []
+            end = now
+            for i, req in enumerate(reqs):
+                lane = i % len(lanes)
+                data, done = self._one_get(req, lanes[lane], conc)
+                lanes[lane] = done
+                end = max(end, done)
+                out.append(data)
+            return out, end
 
     # ----------------------------------------------------------------- write
     def write(self, key: str, data: bytes, now: float, *,
@@ -165,30 +167,31 @@ class StoreClient:
         Recording mode: writes the real bytes (and the ``.dw`` twin) now,
         records the PUT(s) — modeled at ``max(len(data), bill_nbytes)`` —
         and returns the placeholder ``now``."""
-        if self.timeline is not None:
-            wrote = self.store.put(key, data, if_none_match=if_none_match)
-            self.puts += 1
-            nbytes = max(len(data), bill_nbytes or 0)
-            specs = [PutSpec(key, nbytes)]
-            if self.policy.doublewrite and wrote:
-                self.store.put(key + ".dw", data,
-                               if_none_match=if_none_match)
+        with spans.span(spans.STORE_PUT):
+            if self.timeline is not None:
+                wrote = self.store.put(key, data, if_none_match=if_none_match)
                 self.puts += 1
-                specs.append(PutSpec(key + ".dw", nbytes))
-            self.timeline.record_puts(specs)
-            return now
-        dur, nreq = self.policy.wsm.completion(
-            self.store.config.put_model, len(data), self.rng)
-        self.puts += nreq
-        wrote = self.store.put(key, data, if_none_match=if_none_match)
-        end = now + dur
-        if self.policy.doublewrite and wrote:
-            dur2, nreq2 = self.policy.wsm.completion(
+                nbytes = max(len(data), bill_nbytes or 0)
+                specs = [PutSpec(key, nbytes)]
+                if self.policy.doublewrite and wrote:
+                    self.store.put(key + ".dw", data,
+                                   if_none_match=if_none_match)
+                    self.puts += 1
+                    specs.append(PutSpec(key + ".dw", nbytes))
+                self.timeline.record_puts(specs)
+                return now
+            dur, nreq = self.policy.wsm.completion(
                 self.store.config.put_model, len(data), self.rng)
-            self.puts += nreq2
-            self.store.put(key + ".dw", data, if_none_match=if_none_match)
-            end = max(end, now + dur2)                   # both in parallel
-        return end
+            self.puts += nreq
+            wrote = self.store.put(key, data, if_none_match=if_none_match)
+            end = now + dur
+            if self.policy.doublewrite and wrote:
+                dur2, nreq2 = self.policy.wsm.completion(
+                    self.store.config.put_model, len(data), self.rng)
+                self.puts += nreq2
+                self.store.put(key + ".dw", data, if_none_match=if_none_match)
+                end = max(end, now + dur2)                   # both in parallel
+            return end
 
     def stats(self) -> dict:
         return {"gets": self.gets, "puts": self.puts,

@@ -1,11 +1,12 @@
-"""Observability over the event engine (tracing, metrics, drift, reports).
+"""Observability of the query engine, on two clocks.
 
-The coordinator exposes a read-only observer hook: every logged event
-tuple plus lifecycle kinds (QUERY_START .. QUERY_DONE) stream to
-attached observers at the event pop, and observers never feed anything
-back — so results are bit-identical with observability on or off (the
-no-perturbation contract, gated by ``benchmarks/obs.py``). Four
-consumers of that stream live here:
+**Virtual clock: observers of the event stream.** The coordinator exposes
+a read-only observer hook: every logged event tuple plus lifecycle kinds
+(QUERY_START .. QUERY_DONE) stream to attached observers at the event
+pop, and observers never feed anything back — so results are
+bit-identical with observability on or off (the no-perturbation
+contract, gated by ``benchmarks/obs.py``). Four consumers of that stream
+live here:
 
   * :mod:`repro.obs.trace` — causal span trees (query -> stage -> task
     -> request attempt) with Chrome ``trace_event`` export for
@@ -19,17 +20,28 @@ consumers of that stream live here:
     (ROADMAP item 2a);
   * :mod:`repro.obs.report` — per-tenant / per-query-class rollups of
     workload and fleet runs, as text or JSON.
-"""
-from repro.obs.drift import DriftDetector, DriftReport
-from repro.obs.metrics import (Counter, Gauge, LogHistogram,
-                               MetricsObserver, MetricsRegistry)
-from repro.obs.report import Report, fleet_report, workload_report
-from repro.obs.trace import (Span, Tracer, from_chrome,
-                             install_global_tracer)
 
-__all__ = [
-    "Counter", "DriftDetector", "DriftReport", "Gauge", "LogHistogram",
-    "MetricsObserver", "MetricsRegistry", "Report", "Span", "Tracer",
-    "fleet_report", "from_chrome", "install_global_tracer",
-    "workload_report",
-]
+**Wall clock: spans on the profiler's clock.** :mod:`repro.obs.spans`
+holds the names of the host spans (``repro.query``, ``repro.task``,
+``repro.format.decode``, ``repro.ops.launch`` ...), the device name
+scopes of each task's program and the two row counters, all opened
+where the work happens in ``core/`` and ``relational/device_ops.py``.
+They cost a call each and record nothing unless a profiler trace runs.
+To trace a live ``Session``::
+
+    import jax
+    with jax.profiler.trace("/tmp/starling-trace"):
+        session.submit("q5")
+
+then open the ``.xplane.pb`` under ``/tmp/starling-trace/plugins/
+profile/`` in TensorBoard's profiler, or pass
+``create_perfetto_trace=True`` and load the ``perfetto_trace.json.gz``
+at https://ui.perfetto.dev. The host spans sit on the lines of the
+threads that opened them; the device's ops, named by their scope
+(``join/radix_sort``), on the device's lines, on the same clock.
+
+The package imports none of its modules: import each by its own name
+(``repro.obs.trace``, ``repro.obs.spans``, ...), so that the query path
+can import :mod:`repro.obs.spans` without pulling in the planner (which
+``drift`` imports, and which imports the coordinator).
+"""

@@ -1,0 +1,72 @@
+"""Wall-clock spans and counters of the query path, on the profiler's clock.
+
+The spans are ``jax.profiler.TraceAnnotation``s, so they land on the host
+plane of a profiler trace beside the device's own lines and share its
+clock: a gap in which the device sits idle can be read off against what
+each host thread had open. They are recorded only while a trace runs
+(``jax.profiler.trace(dir)``); otherwise opening one is a call that
+returns at once. The counters are ``jax.monitoring.record_scalar`` calls,
+which go to whatever scalar listeners are registered, and to none by
+default.
+
+Spans, outermost first (ids in brackets are the names that
+``obs.trace.Tracer`` gives the same query, stage and task on the virtual
+clock, so the two clocks' spans of one task share an identifier):
+
+==========================  =============================================
+``repro.query`` [query]     ``Coordinator.run_queries``, the whole call,
+                            on the calling thread; ``query`` holds the
+                            run's unique names, space-separated
+``repro.plan`` [query]      building one plan: expansion and validation
+``repro.sched.wait``        the event loop blocked on the workers
+``repro.task`` [query,      one task attempt on an executor thread
+stage, task]
+``repro.store.get``/``put`` store reads and writes
+``repro.format.decode``     §3.2 object and segment decode
+``repro.format.encode``     §3.2 object and table encode
+``repro.ops.stage``         ``device_ops.run``: spec, pad, ``device_put``
+``repro.ops.launch``        the program's dispatch (trace and build on a
+                            cache miss)
+``repro.ops.wait``          the host waiting for the program's sizes
+``repro.ops.fetch``         the output's copy to the host
+``repro.ops.split``         the output ``Table`` and its partitions
+``repro.merge``             the final stage's merge and sort/limit
+==========================  =============================================
+
+Inside each task's device program ``jax.named_scope`` names the
+operators (``filter``, ``compute``, ``join``, ``aggregate``,
+``partition``, ``output``) and every radix sort (``radix_sort``, so an
+op's name path says whose sort it is: ``join/radix_sort``).
+
+Counters, once per ``device_ops.run`` call: ``ROWS``, the true rows into
+the task's program (probe and build sides), and ``ROWS_PADDED``, the
+padded rows it processed, summed over every execution of it.
+"""
+from __future__ import annotations
+
+import jax
+
+QUERY = "repro.query"
+PLAN = "repro.plan"
+SCHED_WAIT = "repro.sched.wait"
+TASK = "repro.task"
+STORE_GET = "repro.store.get"
+STORE_PUT = "repro.store.put"
+FORMAT_DECODE = "repro.format.decode"
+FORMAT_ENCODE = "repro.format.encode"
+OPS_STAGE = "repro.ops.stage"
+OPS_LAUNCH = "repro.ops.launch"
+OPS_WAIT = "repro.ops.wait"
+OPS_FETCH = "repro.ops.fetch"
+OPS_SPLIT = "repro.ops.split"
+MERGE = "repro.merge"
+
+ROWS = "/repro/device_ops/rows"
+ROWS_PADDED = "/repro/device_ops/rows_padded"
+
+
+def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
+    """The host span ``name``, with ``ids`` as its arguments; use it as a
+    context manager. An id's value must hold no ``,``, ``#`` or ``=``
+    (the profiler's encoding of arguments)."""
+    return jax.profiler.TraceAnnotation(name, **ids)

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.obs import spans
 from repro.relational.table import DictColumn, Table
 
 MIN_BUCKET = 1024           # smallest padded row count
@@ -224,26 +225,29 @@ def _sort_pass(perm, digit):
 def _order(valid, keys=()):
     """Stable permutation: valid rows first, sorted lexicographically by
     the integer keys, then the invalid rows in input order. An LSD radix
-    sort of
-    DIGIT_BITS-bit counting passes, as many per key as its span of values
-    among valid rows needs: XLA's sort takes tens of seconds to compile
-    for the TPU, these loops a few."""
-    perm = _iota(valid.shape[0])
-    for k in reversed(keys):
-        # order-preserving int64 -> uint64, offset from the smallest key
-        u = k.astype(jnp.int64).astype(jnp.uint64) ^ jnp.uint64(1 << 63)
-        lo = jnp.min(jnp.where(valid, u, jnp.uint64(np.iinfo(np.uint64).max)))
-        u = jnp.where(valid, u - lo, 0)
-        bits = 64 - lax.clz(jnp.max(u)).astype(jnp.int32)
+    sort of DIGIT_BITS-bit counting passes, as many per key as its span of
+    values among valid rows needs: XLA's sort takes tens of seconds to
+    compile for the TPU, these loops a few. Its ops sit in a
+    ``radix_sort`` name scope, under the scope of the operator that sorts.
+    """
+    with jax.named_scope("radix_sort"):
+        perm = _iota(valid.shape[0])
+        for k in reversed(keys):
+            # order-preserving int64 -> uint64, offset from the smallest key
+            u = k.astype(jnp.int64).astype(jnp.uint64) ^ jnp.uint64(1 << 63)
+            lo = jnp.min(jnp.where(valid, u,
+                                   jnp.uint64(np.iinfo(np.uint64).max)))
+            u = jnp.where(valid, u - lo, 0)
+            bits = 64 - lax.clz(jnp.max(u)).astype(jnp.int32)
 
-        def body(i, perm, u=u):
-            shift = (i * DIGIT_BITS).astype(jnp.uint64)
-            return _sort_pass(perm, ((u[perm] >> shift)
-                                     & (2 ** DIGIT_BITS - 1)).astype(
-                                         jnp.int32))
-        perm = lax.fori_loop(0, (bits + DIGIT_BITS - 1) // DIGIT_BITS,
-                             body, perm)
-    return _sort_pass(perm, (~valid[perm]).astype(jnp.int32))
+            def body(i, perm, u=u):
+                shift = (i * DIGIT_BITS).astype(jnp.uint64)
+                return _sort_pass(perm, ((u[perm] >> shift)
+                                         & (2 ** DIGIT_BITS - 1)).astype(
+                                             jnp.int32))
+            perm = lax.fori_loop(0, (bits + DIGIT_BITS - 1) // DIGIT_BITS,
+                                 body, perm)
+        return _sort_pass(perm, (~valid[perm]).astype(jnp.int32))
 
 
 def _join(cols, mask, bcols, bn, lkey, rkey, cap_out):
@@ -322,7 +326,10 @@ def _aggregate(cols, mask, keys, aggs):
 @functools.partial(jax.jit, static_argnames=("spec",))
 def _program(cols, n, builds, n_parts, spec):
     """The task pipeline. spec = (ops JSON, partition key or None,
-    partition-id bound, per-join output capacities)."""
+    partition-id bound, per-join output capacities). Each operator's ops
+    sit in a name scope: ``filter``, ``compute``, ``join``,
+    ``aggregate``, ``partition`` (the hash) and ``output`` (the final
+    sort and gather)."""
     ops_json, part_key, p_cap, caps = spec
     cap = next(iter(cols.values())).shape[0]
     mask = _iota(cap) < n
@@ -330,32 +337,40 @@ def _program(cols, n, builds, n_parts, spec):
     for op in json.loads(ops_json):
         kind = op["op"]
         if kind == "filter":
-            mask = mask & jnp.asarray(
-                _eval(cols, op["pred"], jnp)).astype(bool)
+            with jax.named_scope("filter"):
+                mask = mask & jnp.asarray(
+                    _eval(cols, op["pred"], jnp)).astype(bool)
         elif kind == "project":
             cols = {c: cols[c] for c in op["columns"]}
         elif kind == "compute":
-            cols = {**cols, op["name"]: _column(cols, op["expr"],
-                                                mask.shape[0])}
+            with jax.named_scope("compute"):
+                cols = {**cols, op["name"]: _column(cols, op["expr"],
+                                                    mask.shape[0])}
         elif kind == "partial_agg":
-            cols, mask = _aggregate(cols, mask, op["keys"], op["aggs"])
+            with jax.named_scope("aggregate"):
+                cols, mask = _aggregate(cols, mask, op["keys"], op["aggs"])
         else:
             bcols, bn = builds[op["table"]]
-            cols, mask, total = _join(cols, mask, bcols, bn, op["lkey"],
-                                      op["rkey"], caps[len(totals)])
+            with jax.named_scope("join"):
+                cols, mask, total = _join(cols, mask, bcols, bn,
+                                          op["lkey"], op["rkey"],
+                                          caps[len(totals)])
             totals.append(total)
     # output: valid rows first (partition-major when partitioned), stable
-    if part_key is None:
-        order = _order(mask)
-        bounds = None
-    else:
-        h = splitmix64(cols[part_key].astype(jnp.int64)) % n_parts
-        pid = h.astype(jnp.int32)
-        order = _order(mask, [pid])
-        spid = jnp.where(mask[order], pid[order], p_cap)
-        bounds = jnp.searchsorted(spid, _iota(p_cap + 1), side="left")
-    out = {c: v[order] for c, v in cols.items()}
-    return out, jnp.sum(mask), bounds, tuple(totals)
+    if part_key is not None:
+        with jax.named_scope("partition"):
+            pid = (splitmix64(cols[part_key].astype(jnp.int64))
+                   % n_parts).astype(jnp.int32)
+    with jax.named_scope("output"):
+        if part_key is None:
+            order = _order(mask)
+            bounds = None
+        else:
+            order = _order(mask, [pid])
+            spid = jnp.where(mask[order], pid[order], p_cap)
+            bounds = jnp.searchsorted(spid, _iota(p_cap + 1), side="left")
+        out = {c: v[order] for c, v in cols.items()}
+        return out, jnp.sum(mask), bounds, tuple(totals)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
@@ -388,18 +403,29 @@ def run(t: Table, ops: list, builds: dict[str, Table],
     a join op names its build side in ``builds``. Returns the output
     Table, or with ``partition=(key, n)`` its n hash partitions, as
     ``relational.ops.op_partition`` cuts them.
+
+    Each phase runs in its ``repro.ops.*`` span, and the call records the
+    ``spans.ROWS`` and ``spans.ROWS_PADDED`` counters (``obs.spans``).
     """
-    spec, names, dicts = _spec(ops, t, builds, partition, bucket(len(t)))
     n_parts = np.uint64(1 if partition is None else partition[1])
+    joined = {op["table"] for op in ops
+              if op["op"] in ("join", "broadcast_join")}
     with jax.enable_x64(True):
-        cols, n = _to_device(t)
-        dev_builds = {b: _to_device(builds[b]) for b in
-                      {op["table"] for op in ops
-                       if op["op"] in ("join", "broadcast_join")}}
+        with spans.span(spans.OPS_STAGE):
+            spec, names, dicts = _spec(ops, t, builds, partition,
+                                       bucket(len(t)))
+            cols, n = _to_device(t)
+            dev_builds = {b: _to_device(builds[b]) for b in joined}
+        padded = bucket(len(t)) + sum(bucket(len(builds[b])) for b in joined)
+        runs = 0
         while True:
-            out, n_dev, bounds, totals = _program(cols, n, dev_builds,
-                                                  n_parts, spec=spec)
-            n_out, bounds, totals = jax.device_get((n_dev, bounds, totals))
+            with spans.span(spans.OPS_LAUNCH):
+                out, n_dev, bounds, totals = _program(cols, n, dev_builds,
+                                                      n_parts, spec=spec)
+            runs += 1
+            with spans.span(spans.OPS_WAIT):
+                n_out, bounds, totals = jax.device_get(
+                    (n_dev, bounds, totals))
             caps = spec[3]
             if all(tot <= c for tot, c in zip(totals, caps)):
                 break
@@ -407,18 +433,24 @@ def run(t: Table, ops: list, builds: dict[str, Table],
                                      for tot, c in zip(totals, caps)),)
         jax.monitoring.record_event(
             TASK_EVENT, platform=next(iter(n_dev.devices())).platform)
-        n_out = int(n_out)
-        cap_out = next(iter(out.values())).shape[0] if out else 0
-        m = min(bucket(n_out), cap_out)
-        host = jax.device_get(_head(out, m) if m < cap_out else out)
-    cols_out = {}
-    for name in names:
-        a = host[name][:n_out]
-        cols_out[name] = DictColumn(a, dicts[name]) if name in dicts else a
-    table = Table(cols_out)
-    if partition is None:
-        return table
-    if not n_out:
-        return [Table({})] * partition[1]
-    return [table.take(slice(int(bounds[i]), int(bounds[i + 1])))
-            for i in range(partition[1])]
+        jax.monitoring.record_scalar(
+            spans.ROWS, len(t) + sum(len(builds[b]) for b in joined))
+        jax.monitoring.record_scalar(spans.ROWS_PADDED, runs * padded)
+        with spans.span(spans.OPS_FETCH):
+            n_out = int(n_out)
+            cap_out = next(iter(out.values())).shape[0] if out else 0
+            m = min(bucket(n_out), cap_out)
+            host = jax.device_get(_head(out, m) if m < cap_out else out)
+    with spans.span(spans.OPS_SPLIT):
+        cols_out = {}
+        for name in names:
+            a = host[name][:n_out]
+            cols_out[name] = DictColumn(a, dicts[name]) if name in dicts \
+                else a
+        table = Table(cols_out)
+        if partition is None:
+            return table
+        if not n_out:
+            return [Table({})] * partition[1]
+        return [table.take(slice(int(bounds[i]), int(bounds[i + 1])))
+                for i in range(partition[1])]
