@@ -1368,7 +1368,7 @@ class Coordinator:
         is cancelled but billed (itemized in dup_gets/dup_puts)."""
         task = stage.tasks[tidx]
         io = task.io
-        if io is None:
+        if io is None or rq >= len(io.reqs):
             return                  # attempt discarded (§3 worker loss)
         req = io.reqs[rq]
         if req.done or req.end <= t + _EPS:
@@ -1400,7 +1400,7 @@ class Coordinator:
     def _on_req_done(self, ctx: _Ctx, run: _Run, stage: _Stage, tidx: int,
                      rq: int, t: float, is_put: bool):
         io = stage.tasks[tidx].io
-        if io is None:
+        if io is None or rq >= len(io.reqs):
             return                  # attempt discarded (§3 worker loss)
         req = io.reqs[rq]
         if req.done or abs(t - req.end) > _EPS:

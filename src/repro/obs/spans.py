@@ -35,12 +35,17 @@ stage, task]
 
 Inside each task's device program ``jax.named_scope`` names the
 operators (``filter``, ``compute``, ``join``, ``aggregate``,
-``partition``, ``output``) and every radix sort (``radix_sort``, so an
-op's name path says whose sort it is: ``join/radix_sort``).
+``partition``, ``output``), every radix sort (``radix_sort``, so an
+op's name path says whose sort it is: ``join/radix_sort``) and the
+aggregate's float64 segment scatters (``aggregate/segment``).
 
 Counters, once per ``device_ops.run`` call: ``ROWS``, the true rows into
 the task's program (probe and build sides), and ``ROWS_PADDED``, the
-padded rows it processed, summed over every execution of it.
+padded rows it processed, summed over every execution of it. A task
+with a partial aggregate also records ``AGG_COLUMNS``, the aggregate's
+float64 output columns, and ``AGG_SCATTERS``, the segment scatters its
+program emits (one per combiner: add, min, max); a task without one
+records neither.
 """
 from __future__ import annotations
 
@@ -63,6 +68,8 @@ MERGE = "repro.merge"
 
 ROWS = "/repro/device_ops/rows"
 ROWS_PADDED = "/repro/device_ops/rows_padded"
+AGG_COLUMNS = "/repro/device_ops/agg_columns"
+AGG_SCATTERS = "/repro/device_ops/agg_scatters"
 
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:

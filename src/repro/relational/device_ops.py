@@ -276,9 +276,36 @@ def _join(cols, mask, bcols, bn, lkey, rkey, cap_out):
     return out, j < total, total
 
 
+_COMBINER = {"sum": "add", "avg": "add", "count": "add", "min": "min",
+             "max": "max"}
+_INIT = {"add": 0.0, "min": np.inf, "max": -np.inf}
+
+
+def _segments(aggs):
+    """The float64 value columns of a partial aggregate by combiner:
+    {combiner: ([expression, None for ones], [(output name, column)])}.
+    A combiner builds each distinct expression once: count and an avg's
+    ``__count`` share ones, sum and avg of one expression share it."""
+    by = {}
+    for name, fn, expr in aggs:
+        if fn not in _COMBINER:
+            raise ValueError(fn)
+        parts = [(name, None if fn == "count" else expr)]
+        if fn == "avg":
+            parts.append((name + "__count", None))
+        exprs, outs = by.setdefault(_COMBINER[fn], ([], []))
+        for out, e in parts:
+            if e not in exprs:
+                exprs.append(e)
+            outs.append((out, exprs.index(e)))
+    return by
+
+
 def _aggregate(cols, mask, keys, aggs):
     """Partial aggregate; groups in np.unique order (lexicographic int64
-    keys), sums/counts/min/max in float64 accumulated in row order."""
+    keys), sums/counts/min/max in float64 accumulated in row order. One
+    segment scatter per combiner, over all of its value columns, in a
+    ``segment`` name scope."""
     cap = mask.shape[0]
     if keys:
         kv = [cols[k].astype(jnp.int64) for k in keys]
@@ -301,25 +328,18 @@ def _aggregate(cols, mask, keys, aggs):
         out = {}
         out_mask = _iota(cap) < 1           # one group, even of no rows
     ones = jnp.ones(cap, jnp.float64)
-
-    def seg(init, v, how):
-        acc = jnp.full(cap, init, jnp.float64).at[gid]
-        return getattr(acc, how)(v, mode="drop")
-
-    for name, fn, expr in aggs:
-        v = ones if expr is None else _column(cols, expr, cap, np.float64)
-        if fn in ("sum", "avg"):
-            out[name] = seg(0.0, v, "add")
-            if fn == "avg":
-                out[name + "__count"] = seg(0.0, ones, "add")
-        elif fn == "count":
-            out[name] = seg(0.0, ones, "add")
-        elif fn == "min":
-            out[name] = seg(np.inf, v, "min")
-        elif fn == "max":
-            out[name] = seg(-np.inf, v, "max")
-        else:
-            raise ValueError(fn)
+    with jax.named_scope("segment"):
+        for how, (exprs, outs) in _segments(aggs).items():
+            # one scatter of [cap, A] rows, A distinct columns: its cost is
+            # mostly per row; the values are built apart, not inside the
+            # scatter's loop
+            v = lax.optimization_barrier(jnp.stack(
+                [ones if e is None else _column(cols, e, cap, np.float64)
+                 for e in exprs], axis=1))
+            acc = jnp.full((cap, len(exprs)), _INIT[how], jnp.float64).at[gid]
+            seg = getattr(acc, how)(v, mode="drop")
+            res = [seg[:, j] for j in range(len(exprs))]
+            out.update((name, res[j]) for name, j in outs)
     return out, out_mask
 
 
@@ -328,8 +348,9 @@ def _program(cols, n, builds, n_parts, spec):
     """The task pipeline. spec = (ops JSON, partition key or None,
     partition-id bound, per-join output capacities). Each operator's ops
     sit in a name scope: ``filter``, ``compute``, ``join``,
-    ``aggregate``, ``partition`` (the hash) and ``output`` (the final
-    sort and gather)."""
+    ``aggregate`` (its segment scatters in ``aggregate/segment``),
+    ``partition`` (the hash) and ``output`` (the final sort and
+    gather)."""
     ops_json, part_key, p_cap, caps = spec
     cap = next(iter(cols.values())).shape[0]
     mask = _iota(cap) < n
@@ -405,7 +426,9 @@ def run(t: Table, ops: list, builds: dict[str, Table],
     ``relational.ops.op_partition`` cuts them.
 
     Each phase runs in its ``repro.ops.*`` span, and the call records the
-    ``spans.ROWS`` and ``spans.ROWS_PADDED`` counters (``obs.spans``).
+    ``spans.ROWS`` and ``spans.ROWS_PADDED`` counters, and with a partial
+    aggregate ``spans.AGG_COLUMNS`` and ``spans.AGG_SCATTERS``
+    (``obs.spans``).
     """
     n_parts = np.uint64(1 if partition is None else partition[1])
     joined = {op["table"] for op in ops
@@ -436,6 +459,13 @@ def run(t: Table, ops: list, builds: dict[str, Table],
         jax.monitoring.record_scalar(
             spans.ROWS, len(t) + sum(len(builds[b]) for b in joined))
         jax.monitoring.record_scalar(spans.ROWS_PADDED, runs * padded)
+        segs = [_segments(op["aggs"]) for op in ops
+                if op["op"] == "partial_agg"]
+        if segs:
+            jax.monitoring.record_scalar(spans.AGG_COLUMNS, sum(
+                len(outs) for seg in segs for _, outs in seg.values()))
+            jax.monitoring.record_scalar(
+                spans.AGG_SCATTERS, sum(len(seg) for seg in segs))
         with spans.span(spans.OPS_FETCH):
             n_out = int(n_out)
             cap_out = next(iter(out.values())).shape[0] if out else 0

@@ -1,5 +1,6 @@
 """Device path of the worker operators (relational.device_ops) against the
 numpy reference (relational.ops): same rows, same order, same bytes."""
+import re
 import threading
 
 import jax
@@ -12,6 +13,7 @@ from repro.relational import device_ops as D
 from repro.relational import ops as OPS
 from repro.relational.table import (DictColumn, Table, partitions_to_object,
                                     serialize_table)
+from repro.relational.tpch import QUERIES, generate
 
 I64 = np.iinfo(np.int64)
 
@@ -91,6 +93,66 @@ def test_device_ops_match_reference(seed, n, which):
     got = D.run(t, ops, builds, partition)
     want = _reference(t, ops, builds, partition)
     assert _bytes(got) == _bytes(want)
+
+
+# repeated value columns within a combiner: sum and avg of one
+# expression, count beside an avg's count, min and max over two columns
+DUP_EXPR = {"fn": "mul", "args": ["a", {"fn": "one_minus", "args": ["b"]}]}
+DUP_AGGS = [["s", "sum", DUP_EXPR], ["m", "avg", DUP_EXPR],
+            ["c", "count", None], ["q", "avg", "a"], ["lo_a", "min", "a"],
+            ["lo_e", "min", "e"], ["hi_a", "max", "a"], ["hi_b", "max", "b"]]
+
+
+@pytest.mark.parametrize("keys", [["d", "k"], []])
+@pytest.mark.parametrize("n", [0, 1, 1023, 1024, 1025])
+def test_repeated_value_columns_match_reference(keys, n):
+    t = _table(np.random.default_rng(100 + n), n)
+    ops = [{"op": "filter", "pred": {"fn": "lt", "args": ["b", 0.07]}},
+           {"op": "partial_agg", "keys": keys, "aggs": DUP_AGGS}]
+    got = D.run(t, ops, {})
+    assert _bytes(got) == _bytes(_reference(t, ops, {}, None))
+
+
+_SCATTER = re.compile(r'"stablehlo\.scatter".*?unique_indices = (\w+)\}>'
+                      r'.*?\}\) : \([^)]*\) -> tensor<([^>]*)>', re.S)
+
+
+def _segment_scatters(t, ops, cap=1024):
+    """Result types of the float64 scatters with non-unique indices in
+    the lowered task program: the aggregate's segment reductions."""
+    spec, _, _ = D._spec(ops, t, {}, None, cap)
+    with jax.enable_x64(True):
+        cols = {n: jax.ShapeDtypeStruct(
+                    (cap,), (c.codes if isinstance(c, DictColumn)
+                             else np.asarray(c)).dtype)
+                for n, c in t.cols.items()}
+        text = D._program.lower(cols, np.int32(len(t)), {}, np.uint64(1),
+                                spec=spec).as_text()
+    return sorted(ty for unique, ty in _SCATTER.findall(text)
+                  if unique == "false" and ty.endswith("f64"))
+
+
+def _scan_task(query):
+    st = next(s for s in QUERIES[query]()["stages"] if s["name"] == "scan_agg")
+    return generate(0.001, seed=0)["lineitem"].project(st["columns"]), \
+        st["ops"]
+
+
+@pytest.mark.parametrize("task, want", [
+    # six output columns over four distinct value columns: one scatter
+    ("q1", ["1024x4xf64"]),
+    # one column is a scatter of one-value rows
+    ("q6", ["1024x1xf64"]),
+    # add over three distinct columns (count shares avg's ones); min, max
+    ("AGGS", ["1024x1xf64", "1024x1xf64", "1024x3xf64"]),
+])
+def test_one_segment_scatter_per_combiner(task, want):
+    if task == "AGGS":
+        t = _table(np.random.default_rng(0), 100)
+        ops = [{"op": "partial_agg", "keys": ["d", "k"], "aggs": AGGS}]
+    else:
+        t, ops = _scan_task(task)
+    assert _segment_scatters(t, ops) == want
 
 
 def test_splitmix64_bit_identical():

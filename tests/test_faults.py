@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.coordinator import Coordinator
 from repro.core.stragglers import StragglerConfig
@@ -267,6 +267,9 @@ def test_failover_kill_after_must_be_reached():
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10 ** 6),
        loss=st.sampled_from([0.0, 0.2, 0.4]))
+# a PUT's DONE event from a lost attempt pops while the replay has
+# issued fewer requests
+@example(seed=159568, loss=0.2)
 def test_replay_is_byte_identical_and_bills_once(seed, loss):
     """§3.2 immutability: re-running any task against the immutable store
     overwrites every output with identical bytes, and the same query bills

@@ -14,6 +14,7 @@ from repro.core.session import Session
 from repro.obs import spans
 from repro.relational import device_ops as D
 from repro.relational.table import Table, serialize_table
+from repro.relational.tpch import QUERIES, generate
 
 HOST_PLANE = "/host:CPU"
 TASK_SPANS = (spans.STORE_GET, spans.STORE_PUT, spans.FORMAT_DECODE,
@@ -153,6 +154,33 @@ def test_join_overflow_counts_its_rerun():
     assert sc.seen[spans.ROWS_PADDED] == [2 * (D.bucket(40) + D.bucket(50))]
 
 
+@pytest.mark.parametrize("query, columns, scatters", [
+    ("q1", 6, 1),       # four distinct value columns, all summed
+    ("q6", 1, 1),
+    ("mix", 5, 3),      # min, max, and a sum beside an avg and its count
+    ("q12", None, None),  # its scan task has no partial aggregate
+])
+def test_aggregate_counters_of_one_task(query, columns, scatters):
+    tables = generate(0.001, seed=0)
+    if query == "mix":
+        t = _table(3000, 10)
+        ops = [{"op": "partial_agg", "keys": ["k"],
+                "aggs": [["lo", "min", "a"], ["hi", "max", "a"],
+                         ["m", "avg", "a"], ["s", "sum", "a"]]}]
+    else:
+        st = next(s for s in QUERIES[query]()["stages"]
+                  if s["kind"] == "scan" and s["table"] == "lineitem")
+        t, ops = tables["lineitem"].project(st["columns"]), st["ops"]
+    with _Scalars() as sc:
+        D.run(t, ops, {})
+    if columns is None:
+        assert spans.AGG_COLUMNS not in sc.seen
+        assert spans.AGG_SCATTERS not in sc.seen
+    else:
+        assert sc.seen[spans.AGG_COLUMNS] == [columns]
+        assert sc.seen[spans.AGG_SCATTERS] == [scatters]
+
+
 def _strip_metadata(hlo: str) -> str:
     """Compiled HLO text without op metadata and the source tables."""
     hlo = re.sub(r", metadata=\{[^}]*\}", "", hlo)
@@ -187,8 +215,8 @@ def _compiled_program() -> str:
 def test_name_scopes_change_only_metadata(monkeypatch):
     scoped = _compiled_program()
     for scope in ("join/radix_sort", "aggregate/radix_sort",
-                  "output/radix_sort", "filter/", "compute/",
-                  "partition/"):
+                  "aggregate/segment/", "output/radix_sort", "filter/",
+                  "compute/", "partition/"):
         assert scope in scoped, scope
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
