@@ -14,6 +14,7 @@ objects.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import importlib.util
@@ -221,6 +222,9 @@ class Run:
     #                                   query of a traced run's trace
     probes: object = None             # probes.Probes of a traced run
     trace: dict | None = None         # trace.reduce() of a traced run
+    spans: dict | None = None         # spans.reduce() of a traced run
+    counters: dict | None = None      # the program's counters' totals over
+    #                                   a traced run's window
     trace_window_s: float = 0.0
     peaks: dict | None = None
 
@@ -262,6 +266,16 @@ def build(config: dict, seed: int):
         Coordinator(store, splits, None, seed=seed, compute_scale=0))
 
 
+def memory_peaks(chips) -> dict:
+    """``memory_peak_bytes``, the peak of the fullest of the cell's chips,
+    and ``memory_peak_bytes_by_chip``, each chip's in device order."""
+    by_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+               for d in chips]
+    return {"memory_peak_bytes": max(
+                (p for p in by_chip if p is not None), default=None),
+            "memory_peak_bytes_by_chip": by_chip}
+
+
 def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
              t_process: float, devices, log=print) -> dict:
     """Set up, warm up, measure for ``seconds`` and check; returns the
@@ -269,6 +283,7 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
     import jax
     config, traffic = parts["config"], parts["traffic"]
     dev = devices[0]
+    chips = devices[:parts["cell"]["chips"]]
     sess = build(config, seed)
     with BuildCounter() as warm_builds:
         t0 = time.perf_counter()
@@ -279,13 +294,16 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
         f"last pass {warm_s:.3f} s")
 
     probes = tdir = None
+    counters = contextlib.nullcontext()
     if trace:
         from chipbench.probes import Probes
+        from chipbench.spans import RowCounter
         probes = Probes().install()
+        counters = RowCounter()
         tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
     gc.collect()
     setup_s = time.perf_counter() - t_process
-    with BuildCounter() as window_builds:
+    with BuildCounter() as window_builds, counters:
         if trace:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -299,8 +317,7 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
     if probes is not None:
         probes.uninstall()
     print(f"programs built in window: {window_builds.builds}", flush=True)
-    stats = dev.memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
+    memory = memory_peaks(chips)
     del sess
     gc.collect()
 
@@ -312,16 +329,27 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
               attempted=sum(r["start"] < t_end for r in records),
               end=t_end, in_flight=[r for r in records
                                     if r["start"] < t_end < r["end"]],
-              traced=records if trace else [], probes=probes)
+              traced=records if trace else [], probes=probes,
+              counters=counters.totals if trace else None)
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices), "memory_peak_bytes": peak}
+              "count": len(devices), **memory}
     line = {"correct": False, "attempted": run.attempted,
             "failed": sum(r["failed"] for r in records), "metrics": {},
             "device": device}
     if trace:
+        from chipbench import spans as S
         from chipbench import trace as T
         run.peaks = peaks(dev.device_kind)
-        run.trace = T.reduce(T.find_xplane(tdir))
+        xplane = T.find_xplane(tdir)
+        t0 = time.perf_counter()
+        run.trace = T.reduce(xplane)
+        t1 = time.perf_counter()
+        run.spans = S.reduce(xplane)
+        log(f"trace reduced in {t1 - t0:.3f} s, by spans in "
+            f"{time.perf_counter() - t1:.3f} s; busy s by chip "
+            f"{run.trace['busy_by_chip_s']}")
+        log("spans and counters: " + json.dumps(
+            {"spans": run.spans, "counters": run.counters}))
         run.trace_window_s = trace_window_s
         shutil.rmtree(tdir, ignore_errors=True)
         device["busy_s"] = run.trace["busy_s"]

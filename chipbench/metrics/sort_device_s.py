@@ -1,7 +1,7 @@
 """Device seconds per query run under the trace in the operators' radix
-sorts: the union, on the first chip, of the intervals of every op whose
-op_name lies under a ``radix_sort`` name scope (``spans.reduce``, read
-through ``scopes.py``)."""
+sorts: each chip's union of the intervals of every op whose op_name lies
+under a ``radix_sort`` name scope, summed over chips (``spans.reduce``,
+read through ``scopes.py``)."""
 
 
 def read(run):

@@ -27,8 +27,14 @@ columns than the TPC-H spec's, and q14 the two sums rather than their
 ratio). ``dtype`` is the float type every float column and sum is
 computed in: float64 is the configuration's precision, float32 the
 control that has to come out as not correct.
+
+Any other query's answer is ``answer(tables, dtype)`` of
+``answers/<query>.py``, found by name as a metric's reader is.
 """
 from __future__ import annotations
+
+import importlib.util
+import pathlib
 
 import numpy as np
 
@@ -177,8 +183,25 @@ def _q14(t, dtype):
 
 ANSWERS = {"q1": _q1, "q3": _q3, "q5": _q5, "q6": _q6, "q12": _q12,
            "q14": _q14}
+ANSWERS_DIR = pathlib.Path(__file__).resolve().parent / "answers"
+
+
+def answerer(query: str):
+    """``answer(tables, dtype)`` of the query: one of ``ANSWERS``, else
+    that of ``answers/<query>.py``. KeyError for a query with neither."""
+    if query in ANSWERS:
+        return ANSWERS[query]
+    path = ANSWERS_DIR / f"{query}.py"
+    if not path.is_file():
+        raise KeyError(f"no answer for {query!r}: not one of "
+                       f"{sorted(ANSWERS)} and no {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_answer_{query}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.answer
 
 
 def answer(query: str, tables: dict, dtype=np.float64) -> dict:
     """The query's result: column name -> array, rows in its ORDER BY."""
-    return ANSWERS[query](tables, dtype)
+    return answerer(query)(tables, dtype)

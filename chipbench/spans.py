@@ -5,23 +5,24 @@ names its device ops by operator scope (``join/radix_sort``, ...). This
 module reads both from the ``.xplane.pb`` of a ``--trace 1`` window,
 beside ``trace.reduce``, which it leaves as it is:
 
-* **Idle causes.** Every device-idle interval on the first chip, between
-  its first and last op, is swept exactly (not sampled). At each instant
+* **Idle causes.** Every device-idle interval on each chip, between its
+  first and last op, is swept exactly (not sampled). At each instant
   every host thread votes for the innermost ``repro.*`` span it has
   open, except a thread blocked in ``repro.sched.wait``, which waits on
   the others; the cause is the name with the most votes, ties going to
   the first in sorted order (as ``trace._category_at`` breaks them), or
-  ``sched`` while no ``repro.task`` is open on any thread. The causes'
-  seconds sum to the idle time.
+  ``sched`` while no ``repro.task`` is open on any thread. Each chip's
+  gaps are swept against the same votes, and the causes' seconds sum to
+  the chips' total idle time.
 * **Host seconds.** ``repro.query`` self time (its duration less what
   ``repro.sched.wait`` covers on the same thread) and the seconds in
   ``repro.ops.stage`` and ``repro.ops.fetch``, summed over threads.
-* **Device seconds per scope.** The union of the intervals of the first
+* **Device seconds per scope.** The union of the intervals of each
   chip's ops, by the operator scopes in their op_name (``scopes.py``):
-  ``join``, ``join/radix_sort``, ... An op name that maps to op_names of
-  different scopes counts under ``ambiguous``.
+  ``join``, ``join/radix_sort``, ... summed over chips. An op name that
+  maps to op_names of different scopes counts under ``ambiguous``.
 
-``RowCounter`` totals the program's row counters over a window.
+``RowCounter`` totals the program's counters over a window.
 """
 from __future__ import annotations
 
@@ -44,24 +45,26 @@ OPERATORS = ("filter", "compute", "join", "aggregate", "partition",
 SORT = "radix_sort"
 AMBIGUOUS = "ambiguous"
 PROGRAM = "jit__program"
+COUNTER_PREFIX = "/repro/"
 ROWS = "/repro/device_ops/rows"
 ROWS_PADDED = "/repro/device_ops/rows_padded"
 
 
 class RowCounter:
-    """Totals of the program's ``rows`` and ``rows_padded`` counters
-    (``jax.monitoring`` scalars, recorded once per operator task on the
-    executor threads) while registered."""
+    """Totals of every ``jax.monitoring`` scalar the program records under
+    ``/repro/`` (``rows`` and ``rows_padded`` once per operator task, on
+    the executor threads, and whatever counters it adds) while
+    registered, each keyed by the last component of its name."""
 
     def __init__(self):
         self.lock = threading.Lock()
-        self.totals = {"rows": 0, "rows_padded": 0}
+        self.totals: dict[str, float] = {}
 
     def _on_scalar(self, event: str, value, **kw) -> None:
-        key = {ROWS: "rows", ROWS_PADDED: "rows_padded"}.get(event)
-        if key is not None:
+        if event.startswith(COUNTER_PREFIX):
+            key = event.rsplit("/", 1)[-1]
             with self.lock:
-                self.totals[key] += value
+                self.totals[key] = self.totals.get(key, 0) + value
 
     def __enter__(self):
         import jax
@@ -181,30 +184,55 @@ def reduce(path: str) -> dict:
         return reduce_bytes(f.read())
 
 
+def _device_ops(plane) -> tuple[list, int]:
+    """(name, start, end) ns of each op of a device plane, and the device
+    ns of its ``jit__program`` runs."""
+    ops, program_ns = [], 0
+    for line in plane.lines:
+        if line.name == OPS_LINE:
+            ops = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events]
+        elif line.name == MODULES_LINE:
+            program_ns += sum(ev.duration_ns for ev in line.events
+                              if ev.name.startswith(PROGRAM + "("))
+    return ops, program_ns
+
+
+def _by_scope(ops, names: dict) -> dict[str, list]:
+    """One chip's op intervals by the scope of their op_names."""
+    out: dict[str, list] = defaultdict(list)
+    for name, s, e in ops:
+        found = {scope_of(o) for o in names.get(name, ())}
+        scope = found.pop() if len(found) == 1 else \
+            (AMBIGUOUS if found else "")
+        if scope:
+            out[scope].append((s, e))
+    return out
+
+
+def _union_s(intervals) -> float:
+    return sum(e - s for s, e in union(intervals)) * 1e-9
+
+
 def reduce_bytes(raw: bytes) -> dict:
     """Idle seconds by cause, host seconds and device seconds by scope of
-    a serialized ``XSpace``. Returns ``idle_s``, ``idle_causes``
-    ({cause: s}), ``queries`` (``repro.query`` spans), ``sched_self_s``,
-    ``op_transfer_s``, ``scope_device_s`` ({scope: s}, chip 0),
-    ``sort_device_s`` (union over every sort scope), ``scoped_device_s``
-    (union over every scope), ``program_s`` (``jit__program`` device
-    seconds, chip 0) and ``ambiguous`` (op names with more than one
-    op_name)."""
+    a serialized ``XSpace``, the device's summed over every chip with
+    ops. Returns ``idle_s``, ``idle_causes`` ({cause: s}), ``queries``
+    (``repro.query`` spans), ``sched_self_s``, ``op_transfer_s``,
+    ``scope_device_s`` ({scope: s}), ``sort_device_s`` (union over every
+    sort scope), ``scoped_device_s`` (union over every scope),
+    ``program_s`` (``jit__program`` device seconds) and ``ambiguous``
+    (op names with more than one op_name)."""
     pd = ProfileData.from_serialized_xspace(raw)
-    chip = None
+    chips = []
+    program_ns = 0
     threads: dict[str, list] = {}
     for plane in pd.planes:
-        if plane.name.startswith(DEVICE_PREFIX) and chip is None:
-            chip = plane
-            ops, program_s = [], 0.0
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    ops = [(ev.name, ev.start_ns,
-                            ev.start_ns + ev.duration_ns)
-                           for ev in line.events]
-                elif line.name == MODULES_LINE:
-                    program_s += sum(ev.duration_ns for ev in line.events
-                                     if ev.name.startswith(PROGRAM + "("))
+        if plane.name.startswith(DEVICE_PREFIX):
+            ops, ns = _device_ops(plane)
+            program_ns += ns
+            if ops:
+                chips.append((plane.name, ops))
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 spans = [(ev.start_ns, ev.start_ns + ev.duration_ns,
@@ -212,36 +240,35 @@ def reduce_bytes(raw: bytes) -> dict:
                          if ev.name.startswith(PREFIX)]
                 if spans:
                     threads[f"{line.name}/{len(threads)}"] = spans
-    if chip is None or not ops:
+    if not chips:
         raise ValueError("no device operations in the trace")
-    busy = union((s, e) for _, s, e in ops)
-    gaps = [(a[1], b[0]) for a, b in zip(busy, busy[1:])]
-    causes = idle_causes(gaps, threads)
-
-    names = scopes.op_names(raw, chip.name).get(chip.name, {})
-    by_scope: dict[str, list] = defaultdict(list)
-    for name, s, e in ops:
-        found = {scope_of(o) for o in names.get(name, ())}
-        scope = found.pop() if len(found) == 1 else \
-            (AMBIGUOUS if found else "")
-        if scope:
-            by_scope[scope].append((s, e))
-    scope_s = {k: sum(e - s for s, e in union(v)) * 1e-9
-               for k, v in sorted(by_scope.items())}
-    sorts = [iv for k, v in by_scope.items()
-             if k.split("/")[-1] == SORT for iv in v]
-    scoped = [iv for v in by_scope.values() for iv in v]
+    names_of = scopes.op_names(raw, DEVICE_PREFIX)
+    gaps = []
+    scope_s: dict[str, float] = defaultdict(float)
+    sort_s = scoped_s = 0.0
+    ambiguous: set[str] = set()
+    for plane_name, ops in chips:
+        busy = union((s, e) for _, s, e in ops)
+        gaps += [(a[1], b[0]) for a, b in zip(busy, busy[1:])]
+        names = names_of.get(plane_name, {})
+        by_scope = _by_scope(ops, names)
+        for k, v in sorted(by_scope.items()):
+            scope_s[k] += _union_s(v)
+        sort_s += _union_s(iv for k, v in by_scope.items()
+                           if k.split("/")[-1] == SORT for iv in v)
+        scoped_s += _union_s(iv for v in by_scope.values() for iv in v)
+        ambiguous.update(scopes.ambiguous(names))
     return {
         "idle_s": sum(e - s for s, e in gaps) * 1e-9,
-        "idle_causes": causes,
+        "idle_causes": idle_causes(gaps, threads),
         "queries": sum(n == QUERY for spans in threads.values()
                        for _, _, n in spans),
         "sched_self_s": _self_s(threads),
         "op_transfer_s": sum(e - s for spans in threads.values()
                              for s, e, n in spans if n in TRANSFER) * 1e-9,
-        "scope_device_s": scope_s,
-        "sort_device_s": sum(e - s for s, e in union(sorts)) * 1e-9,
-        "scoped_device_s": sum(e - s for s, e in union(scoped)) * 1e-9,
-        "program_s": program_s * 1e-9,
-        "ambiguous": scopes.ambiguous(names),
+        "scope_device_s": dict(sorted(scope_s.items())),
+        "sort_device_s": sort_s,
+        "scoped_device_s": scoped_s,
+        "program_s": program_ns * 1e-9,
+        "ambiguous": sorted(ambiguous),
     }

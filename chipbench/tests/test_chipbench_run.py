@@ -1,6 +1,7 @@
 """The harness's phases on the CPU at a tiny scale: results equal the
 reference's, the entry point refuses the CPU, the control and the
 planted faults come out as not correct."""
+import gzip
 import json
 import pathlib
 import shutil
@@ -17,6 +18,8 @@ from chipbench.compare import as_arrays, compare
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SF = 0.004
+SPANS_TRACE = pathlib.Path(__file__).resolve().parent / "data" / \
+    "spans.xplane.pb.gz"
 
 
 def _parts(config, traffic, object_bytes=64 << 20):
@@ -64,6 +67,37 @@ def test_reference_agrees_with_the_programs_generator_and_oracle():
         assert not differs and err < 1e-12, q
 
 
+ANSWER_FILE = """import numpy as np
+
+
+def answer(tables, dtype):
+    li = tables["lineitem"]
+    return {"lines": np.asarray([len(li["l_quantity"])]),
+            "sum_qty": np.asarray([li["l_quantity"].astype(dtype).sum()])}
+"""
+
+
+def test_a_seventh_query_is_answered_from_its_file(tmp_path, monkeypatch):
+    (tmp_path / "q99.py").write_text(ANSWER_FILE)
+    monkeypatch.setattr(reference, "ANSWERS_DIR", tmp_path)
+    config = _parts("tpch-sf0.1-obj64m", "power")["config"]
+    seed = 2**31 + 7
+    want = reference.answer("q99", dbgen.generate(SF, seed))
+    assert want["lines"][0] > 0 and want["sum_qty"][0] > 0
+    wrong = dict(want, sum_qty=want["sum_qty"] * (1 + 1e-6))
+
+    def record(cols):
+        return {"query": "q99", "start": 0.0, "end": 0.0, "failed": False,
+                "result": control._Answer(cols)}
+    assert harness.check([record(want)], config, seed)["correct"]
+    verdict = harness.check([record(wrong)], config, seed)
+    assert not verdict["correct"]
+    assert verdict["numbers"]["max_rel_err"]["value"] > 1e-8
+    with pytest.raises(KeyError, match="q98"):
+        reference.answerer("q98")
+    assert reference.answerer("q6") is reference.ANSWERS["q6"]
+
+
 def test_float32_control_fails_the_limit():
     parts = harness.resolve("tpch-sf0.1-obj64m.power", ROOT)
     config = dict(parts["config"], scale_factor=0.02)
@@ -73,6 +107,50 @@ def test_float32_control_fails_the_limit():
         assert not verdict["correct"]
         assert verdict["numbers"]["max_rel_err"]["value"] > \
             config["correct"]["max_rel_err"]
+
+
+def test_traced_run_hands_spans_and_counters_to_the_readers(
+        tmp_path, monkeypatch):
+    """A ``--trace 1`` run on the CPU, with the recorded chip trace in
+    place of the CPU's: every per-layer metric of the cell is read, the
+    program's counters among them from this run's own tasks."""
+    from chipbench import trace
+    recorded = tmp_path / "chip.xplane.pb"
+    recorded.write_bytes(gzip.decompress(SPANS_TRACE.read_bytes()))
+    monkeypatch.setattr(trace, "find_xplane", lambda d: str(recorded))
+    v5e = harness.peaks("TPU v5 lite")
+    monkeypatch.setattr(harness, "peaks", lambda kind: v5e)
+    parts = _parts("tpch-sf0.1-obj64m", "scan")
+    line = harness.run_cell(parts, 2**31 + 9, 0.3, True,
+                            time.perf_counter(), jax.devices(),
+                            log=lambda s: None)
+    assert line["correct"], line["checks"]
+    assert set(line["metrics"]) == {m["name"] for m in parts["per_layer"]}
+    assert 50 <= line["metrics"]["op_fill"]["value"] <= 100
+    device = line["device"]
+    assert len(device["memory_peak_bytes_by_chip"]) == 1
+    assert device["busy_s"] > 0 and device["window_s"] > 0
+    assert line["breakdown"]["device_ops"]
+
+
+class _Chip:
+    def __init__(self, peak):
+        self.peak = peak
+
+    def memory_stats(self):
+        return None if self.peak is None else {"peak_bytes_in_use": self.peak}
+
+
+@pytest.mark.parametrize("peaks, fullest", [
+    ([122355200], 122355200),
+    ([300, 900, 100, 500], 900),
+    ([None, 700, None, 200], 700),
+    ([None], None),
+])
+def test_memory_peak_is_the_fullest_chips(peaks, fullest):
+    got = harness.memory_peaks([_Chip(p) for p in peaks])
+    assert got == {"memory_peak_bytes": fullest,
+                   "memory_peak_bytes_by_chip": peaks}
 
 
 def _drop_half(run):

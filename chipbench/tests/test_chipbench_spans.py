@@ -165,6 +165,21 @@ def test_row_counter_totals_the_program_counters():
         100.0 * 610 / 3072)
 
 
+def test_counter_totals_every_program_scalar_of_one_q1_task():
+    from repro.relational import device_ops
+    from repro.relational.tpch import QUERIES, generate
+    stage = next(st for st in QUERIES["q1"]()["stages"]
+                 if st["kind"] == "scan" and st["table"] == "lineitem")
+    t = generate(0.001, seed=0)["lineitem"].project(stage["columns"])
+    with spans.RowCounter() as counters:
+        device_ops.run(t, stage["ops"], {})
+        jax.monitoring.record_scalar("/jax/other/rows", 5)
+        jax.monitoring.record_scalar("repro/rows", 5)
+    assert counters.totals == {"rows": len(t),
+                               "rows_padded": device_ops.bucket(len(t)),
+                               "agg_columns": 6, "agg_scatters": 1}
+
+
 def test_readers_read_nothing_from_a_run_without_spans():
     run = types.SimpleNamespace(traced=[{}], trace=None, probes=None)
     for name in NEW_METRICS:
@@ -175,11 +190,111 @@ def test_readers_read_nothing_from_a_run_without_spans():
 # the recorded trace
 # --------------------------------------------------------------------------
 
+# ``spans.reduce_bytes`` of the recorded trace as it read when it reduced
+# only the first device plane
+ONE_CHIP = {
+    "idle_s": 0.05266677,
+    "idle_causes": {
+        "repro.ops.stage": 0.02328602000000001,
+        "repro.ops.launch": 0.002847993999999942,
+        "repro.ops.wait": 0.004459590000000034,
+        "repro.task": 0.00036233000000000006,
+        "repro.ops.fetch": 0.002684714,
+        "repro.ops.split": 5.991e-05,
+        "repro.format.encode": 0.00048547300000000005,
+        "repro.store.put": 0.00010729000000000001,
+        "sched": 0.00427367,
+        "repro.store.get": 2.3610000000000003e-05,
+        "repro.format.decode": 0.008913309,
+        "repro.merge": 2.4500000000000003e-05,
+        "repro.query": 0.005138360000000001},
+    "queries": 2,
+    "sched_self_s": 0.047194036,
+    "op_transfer_s": 0.15829773600000002,
+    "scope_device_s": {
+        "aggregate": 0.00632,
+        "aggregate/radix_sort": 0.000381097,
+        "filter": 2.1340000000000002e-06,
+        "join": 0.00463409,
+        "join/radix_sort": 0.0017833310000000002,
+        "output": 0.006002062000000001,
+        "output/radix_sort": 0.0036970960000000004,
+        "partition": 3.8186e-05},
+    "sort_device_s": 0.005861524000000001,
+    "scoped_device_s": 0.022857996000000002,
+    "program_s": 0.023025301,
+    "ambiguous": [],
+}
+
+
+def _with_second_chip(raw: bytes) -> bytes:
+    """The serialized XSpace with a copy of its ``/device:TPU:0`` plane,
+    renamed ``/device:TPU:1``, appended: a second chip that ran the same
+    ops at the same times."""
+    buf = memoryview(raw)
+    for field, _, span in scopes.fields(buf):
+        if field != 1:
+            continue
+        name, plane = None, b""
+        for pf, wire, pv in scopes.fields(buf, *span):
+            if wire == 2:
+                payload = bytes(buf[pv[0]:pv[1]])
+                if pf == 2:
+                    name, payload = payload.decode(), b"/device:TPU:1"
+                plane += _len(pf, payload)
+            elif wire == 0:
+                plane += _int(pf, pv)
+            else:
+                plane += _varint(pf << 3 | wire) + pv
+        if name == "/device:TPU:0":
+            return raw + _len(1, plane)
+    raise ValueError("no /device:TPU:0 plane")
+
+
 @pytest.fixture(scope="module")
 def recorded():
     raw = gzip.decompress(DATA.read_bytes())
     old = trace.reduce_profile(ProfileData.from_serialized_xspace(raw))
     return raw, old, spans.reduce_bytes(raw)
+
+
+@pytest.fixture(scope="module")
+def two_chips(recorded):
+    raw = _with_second_chip(recorded[0])
+    return (trace.reduce_profile(ProfileData.from_serialized_xspace(raw)),
+            spans.reduce_bytes(raw))
+
+
+def test_one_chip_reduction_is_unchanged(recorded):
+    assert recorded[2] == ONE_CHIP
+    assert list(recorded[2]["idle_causes"]) == list(ONE_CHIP["idle_causes"])
+
+
+def test_busy_s_is_the_mean_over_chips(recorded, two_chips):
+    one, two = recorded[1], two_chips[0]
+    assert one["busy_by_chip_s"] == [one["busy_s"]]
+    assert two["chips"] == 2
+    assert two["busy_by_chip_s"] == [one["busy_s"]] * 2
+    assert two["busy_s"] == pytest.approx(
+        sum(two["busy_by_chip_s"]) / 2, rel=1e-12)
+    assert two["program_s"] == pytest.approx(
+        {k: 2 * v for k, v in one["program_s"].items()}, rel=1e-12)
+
+
+def test_every_chip_counts_in_the_reduction(recorded, two_chips):
+    _, old, one = recorded
+    two = two_chips[1]
+    for key in ("idle_s", "sort_device_s", "scoped_device_s",
+                "program_s"):
+        assert two[key] == pytest.approx(2 * one[key], rel=1e-12), key
+    assert two["scope_device_s"] == pytest.approx(
+        {k: 2 * v for k, v in one["scope_device_s"].items()}, rel=1e-12)
+    assert two["idle_causes"] == pytest.approx(
+        {k: 2 * v for k, v in one["idle_causes"].items()}, rel=1e-9)
+    idle = 2 * (old["span_s"] - old["busy_s"])
+    assert abs(sum(two["idle_causes"].values()) - idle) <= 1e-3
+    for key in ("queries", "sched_self_s", "op_transfer_s", "ambiguous"):
+        assert two[key] == one[key], key
 
 
 def test_idle_causes_sum_to_the_idle_time(recorded):
@@ -213,8 +328,9 @@ def test_host_spans_of_the_recorded_trace(recorded):
 
 def test_readers_on_the_recorded_trace(recorded):
     _, old, red = recorded
-    run = types.SimpleNamespace(traced=[{}, {}], trace=old, spans=red,
-                                counters={"rows": 57, "rows_padded": 100})
+    run = harness.Run(seconds=1.0, setup_s=1.0, window=[], attempted=2,
+                      traced=[{}, {}], trace=old, spans=red,
+                      counters={"rows": 57, "rows_padded": 100})
     got = {name: harness.reader(name)(run) for name in NEW_METRICS}
     assert all(v is not None and v >= 0 for v in got.values()), got
     assert got["sort_device_s"] == pytest.approx(red["sort_device_s"] / 2)

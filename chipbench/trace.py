@@ -97,8 +97,9 @@ def reduce(path: str, top: int = 10) -> dict:
 def reduce_profile(pd, label: str = "trace", top: int = 10) -> dict:
     """Busy union, idle gaps and device time per program and op.
 
-    Returns a dict with ``chips`` (device planes found), ``busy_s`` (union
-    of op intervals, averaged over the chips), ``span_s`` (first op start
+    Returns a dict with ``chips`` (device planes found), ``busy_by_chip_s``
+    (the union of each plane's op intervals, in plane order), ``busy_s``
+    (their mean), ``span_s`` (first op start
     to last op end), ``program_s`` (device seconds per program name),
     ``device_ops`` (the ``top`` ops by device seconds, as [short name,
     s]; a loop's time includes that of the ops in its body) and
@@ -145,6 +146,7 @@ def reduce_profile(pd, label: str = "trace", top: int = 10) -> dict:
     ops = sorted(op_s.items(), key=lambda kv: -kv[1])[:top]
     return {
         "chips": len(busy_by_chip),
+        "busy_by_chip_s": busy,
         "busy_s": sum(busy) / len(busy),
         "span_s": (first_busy[-1][1] - first_busy[0][0]) * 1e-9,
         "program_s": dict(program_s),
