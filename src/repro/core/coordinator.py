@@ -89,6 +89,15 @@ Determinism invariants:
     what direct placement would have computed, so wall-clock resolution
     order never leaks into virtual time.
 
+Task placement: the coordinator holds the host's chips (``devices``, by
+default ``jax.local_devices()``) and runs each task's device program on
+``devices[k]``, ``k = (tasks of the plan's earlier stages + task index)
+mod len(devices)``. A stage's tasks differ by at most one between chips,
+a query's stages continue round the chips, and a retried task runs on
+the chip of its first attempt. The rule depends on the plan
+alone, so one pass of a query builds every (program, chip) pair that a
+later pass of it uses. Placement moves no virtual time.
+
 Multi-stage shuffles (§4.2) are expanded statically: combiner stages are
 spliced into a private working copy of the plan (and into the join's deps),
 never into the caller's object, so a plan dict can be re-run any number of
@@ -105,6 +114,7 @@ import zlib
 from collections import deque
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
+import jax
 import numpy as np
 
 from repro.core.cost import WORKER_MEM_GB, QueryCost
@@ -252,6 +262,7 @@ class _Stage:
         self.ready_t = 0.0
         self.backup_armed = False
         self.median = 0.0
+        self.first = 0          # tasks of the plan's earlier stages
 
 
 class _TenantState:
@@ -366,7 +377,7 @@ class Coordinator:
                  faults: FaultInjector | FaultConfig | None = None,
                  coldstart: ColdStartConfig | None = None,
                  retry: RetryPolicy | None = None,
-                 journal=None):
+                 journal=None, devices: list | None = None):
         self.store = store
         self.base_splits = base_splits
         self.policy = policy or StragglerConfig()
@@ -375,6 +386,9 @@ class Coordinator:
         self.compute_scale = compute_scale
         self.executor_workers = executor_workers or min(8, os.cpu_count()
                                                         or 1)
+        # the chips that run the tasks' device programs (task placement)
+        self.devices = list(jax.local_devices() if devices is None
+                            else devices)
         # §3 fault path (repro.faults): all None/disabled by default, in
         # which case every scheduling code path is bit-identical to the
         # fault-free engine (strict-superset contract)
@@ -607,6 +621,9 @@ class Coordinator:
         tstates: dict[str, _TenantState] = {}
         runs: list[_Run] = []
         with spans.span(spans.QUERY) as query_span:
+            # a chip held but given no task reads 0, not absent
+            for d in self.devices:
+                jax.monitoring.record_scalar(spans.rows_padded_chip(d.id), 0)
             for ridx, (plan, arr) in enumerate(zip(plans, arrivals)):
                 if afters[ridx] is not None:
                     arr = math.nan    # set when the upstream run finishes
@@ -625,8 +642,11 @@ class Coordinator:
                     if spec.name not in tstates:
                         tstates[spec.name] = _TenantState(spec)
                     run.tenant = tstates[spec.name]
+                first = 0
                 for stage in run.stages:
+                    stage.first = first
                     stage.n = self._ntasks(expanded, stage.st)
+                    first += stage.n
                     stage.undispatched = stage.n
                     stage.tasks = [_Task() for _ in range(stage.n)]
                     run.keys[stage.st["name"]] = [None] * stage.n
@@ -984,9 +1004,14 @@ class Coordinator:
             return
         worker = Worker(self.store, self.policy,
                         self._task_rng(run, stage.sidx, tidx, 0),
-                        self.compute_scale)
+                        self.compute_scale, device=self._device(stage, tidx))
         call = self._build_task(run, stage.st, tidx, worker, start)
         ctx.outstanding[ctx.pool.submit(call)] = (run, stage, tidx)
+
+    def _device(self, stage: "_Stage", tidx: int):
+        """The chip that runs task ``tidx`` of ``stage`` (task placement:
+        the module docstring)."""
+        return self.devices[(stage.first + tidx) % len(self.devices)]
 
     def _modeled_result(self, st: dict, tidx: int) -> TaskResult:
         """Synthetic TaskResult for a "modeled" stage task: a single
@@ -1823,9 +1848,12 @@ class Coordinator:
             call = lambda: w.run_final(query, st, inputs, start)
         else:
             raise ValueError(kind)
-        # the ids obs.trace.Tracer gives the same task on the virtual clock
+        # the ids obs.trace.Tracer gives the same task on the virtual clock,
+        # and the chip of a task that runs a device program
         ids = {"query": query, "stage": st["name"],
                "task": f"{st['name']}[{ti}]"}
+        if kind in ("scan", "join"):
+            ids["chip"] = w.device.id
 
         def traced():
             with spans.span(spans.TASK, **ids):

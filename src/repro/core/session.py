@@ -182,7 +182,8 @@ class Session:
             record_events=c.event_log is not None
             if record_events is None else record_events,
             max_events=c.max_events, faults=c.faults,
-            coldstart=c.coldstart, retry=c.retry, journal=journal)
+            coldstart=c.coldstart, retry=c.retry, journal=journal,
+            devices=c.devices)
 
     @staticmethod
     def failover(make_coordinator, plan: dict, *, kill_after: int,

@@ -4,7 +4,8 @@ A worker receives ONLY its task parameters, reads inputs from the object
 store (base table splits or §3.2 partitioned intermediates), executes its
 operator pipeline as one jitted device program (``relational.device_ops``),
 writes its output object(s), and exits. No worker-to-worker
-communication exists — the store is the only medium.
+communication exists — the store is the only medium. The program runs
+on the chip the coordinator placed the task on (``device``).
 
 Timing is *not* decided here: the worker moves real bytes eagerly and
 records every store request into a :class:`RequestTimeline`
@@ -103,8 +104,10 @@ class Worker:
     """Executes one task; records its request timeline for the scheduler."""
 
     def __init__(self, store: ObjectStore, policy: StragglerConfig,
-                 rng: np.random.Generator, compute_scale: float = 1.0):
+                 rng: np.random.Generator, compute_scale: float = 1.0,
+                 device=None):
         self.store = store
+        self.device = device        # the task's chip; None: JAX's default
         self.policy = policy
         self.timeline = RequestTimeline()
         self.client = StoreClient(store, policy, rng, timeline=self.timeline)
@@ -201,7 +204,8 @@ class Worker:
         # are provably no-rows-pass, so skip them (filters would KeyError)
         if t.cols:
             ops = st.get("ops", [])
-            out = DOPS.run(t, ops, _builds(ops, base_reader), part)
+            with DOPS.on_chip(self.device):
+                out = DOPS.run(t, ops, _builds(ops, base_reader), part)
         else:
             out = [Table({})] * n_out_parts if part else t
         comp = (time.thread_time() - c0) * self.compute_scale
@@ -223,7 +227,8 @@ class Worker:
             builds[st["right"]] = right
             join = {"op": "join", "table": st["right"], "lkey": st["lkey"],
                     "rkey": st["rkey"]}
-            out = DOPS.run(left, [join] + ops, builds, part)
+            with DOPS.on_chip(self.device):
+                out = DOPS.run(left, [join] + ops, builds, part)
         else:
             out = [Table({})] * n_out_parts if part else Table({})
         comp = (time.thread_time() - c0) * self.compute_scale

@@ -19,8 +19,11 @@ clock, so the two clocks' spans of one task share an identifier):
                             run's unique names, space-separated
 ``repro.plan`` [query]      building one plan: expansion and validation
 ``repro.sched.wait``        the event loop blocked on the workers
-``repro.task`` [query,      one task attempt on an executor thread
-stage, task]
+``repro.task`` [query,      one task attempt on an executor thread; a
+stage, task]                scan or join task, whose operators run as a
+                            device program, also carries the id
+                            ``chip``: the JAX device id of the chip it
+                            was placed on (``core.coordinator``)
 ``repro.store.get``/``put`` store reads and writes
 ``repro.format.decode``     §3.2 object and segment decode
 ``repro.format.encode``     §3.2 object and table encode
@@ -39,13 +42,31 @@ operators (``filter``, ``compute``, ``join``, ``aggregate``,
 op's name path says whose sort it is: ``join/radix_sort``) and the
 aggregate's float64 segment scatters (``aggregate/segment``).
 
-Counters, once per ``device_ops.run`` call: ``ROWS``, the true rows into
-the task's program (probe and build sides), and ``ROWS_PADDED``, the
-padded rows it processed, summed over every execution of it. A task
-with a partial aggregate also records ``AGG_COLUMNS``, the aggregate's
-float64 output columns, and ``AGG_SCATTERS``, the segment scatters its
-program emits (one per combiner: add, min, max); a task without one
-records neither.
+Counters:
+
+===============================  ==========================================
+``ROWS``                         once per ``device_ops.run`` call: the true
+                                 rows into the task's program (probe and
+                                 build sides)
+``ROWS_PADDED``                  once per call: the padded rows it
+                                 processed, summed over every execution of
+                                 it (a join's re-run after an overflow)
+``rows_padded_chip(k)``,         once per call of a task the coordinator
+``.../rows_padded_chip<k>``      placed (inside ``device_ops.on_chip``):
+                                 the same number under the chip ``k`` (JAX
+                                 device id, the ``<k>`` of its trace plane
+                                 ``/device:TPU:<k>``) that ran it; and 0
+                                 for every chip the coordinator holds,
+                                 once per ``Coordinator.run_queries`` call,
+                                 so a chip given no task reads 0 and is not
+                                 absent
+``AGG_COLUMNS``                  once per call with a partial aggregate:
+                                 the aggregate's float64 output columns
+``AGG_SCATTERS``                 with it: the segment scatters its program
+                                 emits (one per combiner: add, min, max); a
+                                 task without a partial aggregate records
+                                 neither
+===============================  ==========================================
 """
 from __future__ import annotations
 
@@ -70,6 +91,11 @@ ROWS = "/repro/device_ops/rows"
 ROWS_PADDED = "/repro/device_ops/rows_padded"
 AGG_COLUMNS = "/repro/device_ops/agg_columns"
 AGG_SCATTERS = "/repro/device_ops/agg_scatters"
+
+
+def rows_padded_chip(k: int) -> str:
+    """The counter of the padded rows that chip ``k`` processed."""
+    return f"{ROWS_PADDED}_chip{k}"
 
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:

@@ -3,11 +3,12 @@
 A task's per-row work — filter and compute expressions, broadcast and
 partitioned equi-joins (sort-probe), partial aggregation, and the hash
 partition into the §3.2 partition-major layout — runs as ONE jitted JAX
-program on the default device. The host keeps what the design puts there:
-the §3.2 object decode before the program and the encode after it (the
-store is the only medium, so a task's bytes cross the host at every GET
-and PUT), and the final merge and sort/limit over the few-row partials
-(``relational.ops``).
+program on the task's chip (``on_chip``, the coordinator's task
+placement; JAX's default device outside it). The host keeps what the
+design puts there: the §3.2 object decode before the program and the
+encode after it (the store is the only medium, so a task's bytes cross
+the host at every GET and PUT), and the final merge and sort/limit over
+the few-row partials (``relational.ops``).
 
 The answers are those of ``relational.ops`` (the numpy reference that
 ``engine.oracle`` runs), row for row and byte for byte on the CPU:
@@ -33,8 +34,10 @@ probe side's bucket) is re-run with a larger one.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +56,22 @@ _BIN = {"add": "add", "sub": "subtract", "mul": "multiply",
         "ge": "greater_equal", "eq": "equal", "ne": "not_equal",
         "and": "logical_and", "or": "logical_or"}
 _I64_MAX = np.iinfo(np.int64).max
+_placed = threading.local()     # .device: the chip of this thread's tasks
+
+
+@contextlib.contextmanager
+def on_chip(device):
+    """Run this thread's ``run`` calls inside the block on ``device``, the
+    chip the coordinator placed the task on, and count their padded rows
+    under its ``spans.rows_padded_chip`` counter. Outside such a block
+    (or with None) ``run`` uses JAX's default device and records no
+    per-chip counter."""
+    prev = getattr(_placed, "device", None)
+    _placed.device = device
+    try:
+        yield
+    finally:
+        _placed.device = prev
 
 
 def bucket(n: int, floor: int = MIN_BUCKET) -> int:
@@ -122,8 +141,9 @@ def _schema(ops: list, t: Table, builds: dict):
     return resolved, names, dicts
 
 
-def _to_device(t: Table):
-    """(name -> padded column, row count) on the default device."""
+def _to_device(t: Table, device=None):
+    """(name -> padded column, row count), the columns put on ``device``
+    (JAX's default device when None)."""
     n = len(t)
     cap = bucket(n)
     cols = {}
@@ -132,7 +152,7 @@ def _to_device(t: Table):
         p = np.zeros(cap, a.dtype)
         p[:n] = a
         cols[name] = p
-    return jax.device_put(cols), np.int32(n)
+    return jax.device_put(cols, device), np.int32(n)
 
 
 # ---------------------------------------------------------------------------
@@ -417,7 +437,9 @@ def _spec(ops: list, t: Table, builds: dict, partition, cap: int):
 
 def run(t: Table, ops: list, builds: dict[str, Table],
         partition: tuple[str, int] | None = None):
-    """Run a task's ops on the device.
+    """Run a task's ops on the device: the chip of the enclosing
+    ``on_chip`` block, where the probe side and every build side are put
+    so that the program runs there, else JAX's default device.
 
     ``ops`` are plan ops (filter / project / compute / partial_agg /
     broadcast_join) plus ``{"op": "join", "table", "lkey", "rkey"}``;
@@ -426,10 +448,12 @@ def run(t: Table, ops: list, builds: dict[str, Table],
     ``relational.ops.op_partition`` cuts them.
 
     Each phase runs in its ``repro.ops.*`` span, and the call records the
-    ``spans.ROWS`` and ``spans.ROWS_PADDED`` counters, and with a partial
-    aggregate ``spans.AGG_COLUMNS`` and ``spans.AGG_SCATTERS``
-    (``obs.spans``).
+    ``spans.ROWS`` and ``spans.ROWS_PADDED`` counters, inside ``on_chip``
+    the padded rows again under the chip's ``spans.rows_padded_chip``,
+    and with a partial aggregate ``spans.AGG_COLUMNS`` and
+    ``spans.AGG_SCATTERS`` (``obs.spans``).
     """
+    device = getattr(_placed, "device", None)
     n_parts = np.uint64(1 if partition is None else partition[1])
     joined = {op["table"] for op in ops
               if op["op"] in ("join", "broadcast_join")}
@@ -437,8 +461,8 @@ def run(t: Table, ops: list, builds: dict[str, Table],
         with spans.span(spans.OPS_STAGE):
             spec, names, dicts = _spec(ops, t, builds, partition,
                                        bucket(len(t)))
-            cols, n = _to_device(t)
-            dev_builds = {b: _to_device(builds[b]) for b in joined}
+            cols, n = _to_device(t, device)
+            dev_builds = {b: _to_device(builds[b], device) for b in joined}
         padded = bucket(len(t)) + sum(bucket(len(builds[b])) for b in joined)
         runs = 0
         while True:
@@ -459,6 +483,9 @@ def run(t: Table, ops: list, builds: dict[str, Table],
         jax.monitoring.record_scalar(
             spans.ROWS, len(t) + sum(len(builds[b]) for b in joined))
         jax.monitoring.record_scalar(spans.ROWS_PADDED, runs * padded)
+        if device is not None:
+            jax.monitoring.record_scalar(spans.rows_padded_chip(device.id),
+                                         runs * padded)
         segs = [_segments(op["aggs"]) for op in ops
                 if op["op"] == "partial_agg"]
         if segs:

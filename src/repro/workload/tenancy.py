@@ -250,7 +250,8 @@ class _ModelBank:
                              seed=coord.seed,
                              max_parallel=coord.max_parallel,
                              compute_scale=0.0,
-                             executor_workers=coord.executor_workers)
+                             executor_workers=coord.executor_workers,
+                             devices=coord.devices)
             c2._name_counts[modeled["name"]] = instance
             l0 = c2.run_query(copy.deepcopy(modeled)).latency_s
             if l0 <= 0.0 or l_exact <= 0.0:

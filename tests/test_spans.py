@@ -96,6 +96,18 @@ def test_tasks_nest_inside_the_query_and_hold_the_work(traced):
                        for t in tasks), e["name"]
 
 
+def test_operator_tasks_carry_their_chip(traced):
+    evs, _ = traced
+    tasks = [e for e in evs if e["name"] == spans.TASK]
+    chip = jax.devices()[0].id
+    for t in tasks:
+        if t["ids"]["stage"] == "final":        # the merge, on the host
+            assert "chip" not in t["ids"]
+        else:
+            assert int(t["ids"]["chip"]) == chip, t["ids"]
+    assert any("chip" in t["ids"] for t in tasks)
+
+
 def test_results_are_bit_identical_with_a_trace_running(traced):
     _, res = traced
     plain = _session()
@@ -138,6 +150,44 @@ def test_row_counters_of_one_task(n):
         D.run(t, ops, {})
     assert sc.seen[spans.ROWS] == [n]
     assert sc.seen[spans.ROWS_PADDED] == [D.bucket(n)]
+
+
+def test_chip_counter_of_one_task():
+    t = _table(700, 10)
+    ops = [{"op": "filter", "pred": {"fn": "lt", "args": ["a", 0.5]}}]
+    dev = jax.devices()[0]
+    with _Scalars() as sc:
+        with D.on_chip(dev):            # a task placed on a chip
+            D.run(t, ops, {})
+        D.run(t, ops, {})               # JAX's default device: no chip's
+    assert sc.seen[spans.rows_padded_chip(dev.id)] == [D.bucket(700)]
+    assert sc.seen[spans.ROWS_PADDED] == [D.bucket(700)] * 2
+    assert spans.rows_padded_chip(dev.id) == \
+        f"/repro/device_ops/rows_padded_chip{dev.id}"
+
+
+class _IdleChip:
+    """A chip the coordinator holds; placement gives it only the host's
+    final merge, so it runs no device program."""
+    id = 7
+
+
+def test_a_chip_given_no_task_reads_zero():
+    from repro.core.coordinator import Coordinator
+    from repro.core.engine import load_base_tables
+    from repro.objectstore.store import ObjectStore, StoreConfig
+    store = ObjectStore(StoreConfig(seed=0, time_scale=0.0,
+                                    simulate_visibility_lag=False))
+    splits = load_base_tables(store, generate(0.002, seed=0), 64 << 20)
+    dev = jax.devices()[0]
+    coord = Coordinator(store, splits, seed=0, compute_scale=0,
+                        devices=[dev, _IdleChip()])
+    with _Scalars() as sc:
+        res = coord.run_query(QUERIES["q6"]())   # one scan task, then merge
+    assert not res.failed
+    assert sc.seen[spans.rows_padded_chip(7)] == [0]
+    ran = sc.seen[spans.rows_padded_chip(dev.id)]
+    assert ran[0] == 0 and sum(ran) == sum(sc.seen[spans.ROWS_PADDED]) > 0
 
 
 def test_join_overflow_counts_its_rerun():
